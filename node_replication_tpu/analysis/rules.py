@@ -117,7 +117,7 @@ _HOST_SYNC_METHODS = {
 )
 def host_sync_in_jit(mod: ModuleInfo,
                      project: Project) -> Iterator[Diagnostic]:
-    """The hot-path contract (BENCH_NOTES methodology, `utils/fence.py`):
+    """The hot-path contract (`utils/fence.py`):
     no host synchronization inside traced code. `.item()`,
     `np.asarray`, `jax.device_get`, `block_until_ready` either fail at
     trace time or silently constant-fold one trace-time value into the

@@ -138,6 +138,17 @@ class LogState(NamedTuple):
     ltails: jax.Array  # int64[R] (per-replica local tails)
 
 
+def host_cursors(ltails, tail) -> tuple[np.ndarray, int]:
+    """Host copies of a fleet's cursors, `(ltails, tail)`, fetched side
+    by side in one round trip (`jax.device_get` starts both transfers
+    before waiting). Not a device-side concatenate: on a mesh `ltails`
+    is replica-sharded and `tail` replicated, and the v5e compiler
+    aborts on that int64 concatenate ("Unsupported conversion from
+    vmreg/vreg to U64")."""
+    lts, t = jax.device_get((ltails, tail))
+    return np.array(lts), int(t)
+
+
 def log_init(spec: LogSpec) -> LogState:
     """Allocate an empty log (`Log::new`, `nr/src/log.rs:179-241`)."""
     return LogState(

@@ -87,12 +87,14 @@ def save_durable_snapshot(nr, directory: str,
         tail = int(np.asarray(nr.log.tail))
         path = snapshot_path(directory, tail)
         nr.checkpoint(path)
+        # read under the lock: a concurrent round donates the log
+        head = int(np.asarray(nr.log.head))
     get_registry().counter("recovery.snapshots").inc()
     get_tracer().emit("durable-snapshot", pos=tail, path=path)
     wal = getattr(nr, "wal", None)
     if wal is not None:
         wal.reclaim_floor = max(wal.reclaim_floor, tail)
-        wal.maybe_reclaim(int(np.asarray(nr.log.head)))
+        wal.maybe_reclaim(head)
     for _, old in list_snapshots(directory)[max(1, int(keep)):]:
         if old != path:
             os.remove(old)

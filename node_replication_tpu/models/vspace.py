@@ -446,7 +446,7 @@ def make_vspace_radix(n_pages: int, max_span: int = 16) -> Dispatch:
         the replica's own tables). r4 relied on XLA hoisting the sorts
         out of the replica vmap, which it does not do for
         gather/scatter-carrying pipelines — the split makes long-log
-        throughput scale linearly with R (BENCH_NOTES r5).
+        throughput scale with R.
         """
         W = opcodes.shape[0]
         S = max_span

@@ -1,8 +1,11 @@
 """Native host-side engine: build-on-demand ctypes binding.
 
 The shared library is compiled from `nr_native.cpp` with the system g++ the
-first time it is needed (and whenever the source is newer than the cached
-`.so`). No pip/pybind dependency: the C ABI is consumed with ctypes.
+first time it is needed. Its file name carries a hash of the source
+(`libnr_native-<sha256[:16]>.so`), so only a library built from the
+source in this checkout is ever loaded — a stale or copied-in `.so` of
+another source is never picked up, whatever its mtime. No pip/pybind
+dependency: the C ABI is consumed with ctypes.
 
 Race detection (EXCEEDS the reference, which ships none — SURVEY.md §5
 "race detection: none"): set `NR_TPU_TSAN=1` before first import to
@@ -16,6 +19,7 @@ The TSAN build lands in a separate `.so` so the fast build is untouched.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -23,8 +27,11 @@ import threading
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "nr_native.cpp")
 _TSAN = os.environ.get("NR_TPU_TSAN", "") == "1"
+with open(_SRC, "rb") as _f:
+    _SRC_HASH = hashlib.sha256(_f.read()).hexdigest()[:16]
 _SO = os.path.join(
-    _DIR, "libnr_native_tsan.so" if _TSAN else "libnr_native.so"
+    _DIR,
+    f"libnr_native{'_tsan' if _TSAN else ''}-{_SRC_HASH}.so",
 )
 
 _lock = threading.Lock()
@@ -32,13 +39,10 @@ _lib = None
 
 
 def build(force: bool = False) -> str:
-    """Compile the native library if missing/stale; return the .so path."""
+    """Compile the native library unless this source's build exists;
+    return the .so path."""
     with _lock:
-        if (
-            not force
-            and os.path.exists(_SO)
-            and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)
-        ):
+        if not force and os.path.exists(_SO):
             return _SO
         # pid-unique temp path: concurrent processes may race the build;
         # each compiles privately, then atomically publishes.

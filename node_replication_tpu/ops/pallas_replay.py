@@ -281,42 +281,37 @@ def pallas_hashmap_state(n_keys: int, n_replicas: int):
 # ---------------------------------------------------------------------------
 
 
-def _fused_hashmap_kernel(meta_ref, opc_ref, key_ref, val_ref,
-                          app_opc_lo, app_args_lo, app_opc_hi,
-                          app_args_hi, ring_opc_in, ring_args_in,
-                          val_in, pres_in, *rest,
-                          n_keys: int, window: int, win_rows: int,
-                          fenced: bool):
+def _fused_hashmap_kernel(meta_ref, opc_ref, key_ref, val_ref, *refs,
+                          n_planes: int, n_keys: int, window: int,
+                          win_rows: int, fenced: bool):
     """One combiner round: ring-window append (DMA, grid step 0) +
     in-order replay of the SMEM batch into the `[Kp, tile_r]` state
     blocks + response gather. `meta = [s_lo, count]`; batch slots at or
     past `count` are NOOP by the `encode_ops` contract, so the replay
-    loop needs no count gate. With `fenced`, an extra `[1, tile_r]`
-    int32 plane marks quarantined lanes: they replay in VMEM like
-    everyone (keeping the loop branch-free) but their writeback is
-    restored from the input at the end — state and responses of a
-    fenced replica must not move (the caller zeroes their resp rows)."""
-    from node_replication_tpu.ops.pallas_ring import ring_append_dma
+    loop needs no count gate. `refs` opens with the `n_planes` lo
+    spans, hi spans and aliased ring inputs (`split_ring_refs`). With
+    `fenced`, an extra `[1, tile_r]` int32 plane marks quarantined
+    lanes: they replay in VMEM like everyone (keeping the loop
+    branch-free) but their writeback is restored from the input at the
+    end — state and responses of a fenced replica must not move (the
+    caller zeroes their resp rows)."""
+    from node_replication_tpu.ops.pallas_ring import (
+        ring_append_dma,
+        split_ring_refs,
+    )
 
-    if fenced:
-        (fen_in, ring_opc_out, ring_args_out, val_out, pres_out,
-         resp_ref, sem) = rest
-    else:
-        (ring_opc_out, ring_args_out, val_out, pres_out, resp_ref,
-         sem) = rest
-        fen_in = None
+    lo, hi, _ring_in, rest = split_ring_refs(refs, n_planes)
+    val_in, pres_in, *rest = rest
+    fen_in = rest.pop(0) if fenced else None
+    ring_out = rest[:n_planes]
+    val_out, pres_out, resp_ref, sem = rest[n_planes:]
     # the ring content only flows through the aliasing: the replay
     # reads the batch from SMEM (append happens-before replay by the
     # lock-step data dependence, core/log.py)
-    del ring_opc_in, ring_args_in
     with x64_disabled():
         @pl.when(pl.program_id(0) == 0)
         def _append():
-            ring_append_dma(
-                sem, meta_ref[0], win_rows,
-                (app_opc_lo, app_args_lo), (app_opc_hi, app_args_hi),
-                (ring_opc_out, ring_args_out),
-            )
+            ring_append_dma(sem, meta_ref[0], win_rows, lo, hi, ring_out)
 
         val_out[:] = val_in[:]
         pres_out[:] = pres_in[:]
@@ -410,41 +405,38 @@ def make_fused_hashmap_calls(
             f"n_keys={n_keys}, window={window}; fall back to the "
             f"append+exec chain for this config"
         )
+    n_planes = 1 + A
     kernel = functools.partial(
-        _fused_hashmap_kernel, n_keys=n_keys, window=window,
-        win_rows=win, fenced=fenced,
+        _fused_hashmap_kernel, n_planes=n_planes, n_keys=n_keys,
+        window=window, win_rows=win, fenced=fenced,
     )
     smem = lambda: pl.BlockSpec(memory_space=pltpu.SMEM)
     anyspec = lambda: pl.BlockSpec(memory_space=pltpu.ANY)
+    vmem = lambda: pl.BlockSpec(memory_space=pltpu.VMEM)
+    ring_shape = jax.ShapeDtypeStruct((rows, 128), jnp.int32)
 
     def build_call(sub_r: int):
         state_spec = pl.BlockSpec((kp, tile_r), lambda i: (0, i))
-        in_specs = [
-            smem(),                                   # meta
-            smem(), smem(), smem(),                   # opc/key/val
-            pl.BlockSpec(memory_space=pltpu.VMEM),    # app_opc_lo
-            pl.BlockSpec(memory_space=pltpu.VMEM),    # app_args_lo
-            pl.BlockSpec(memory_space=pltpu.VMEM),    # app_opc_hi
-            pl.BlockSpec(memory_space=pltpu.VMEM),    # app_args_hi
-            anyspec(), anyspec(),                     # ring planes
-            state_spec, state_spec,                   # values/present
-        ]
+        in_specs = (
+            [smem()] * 4                          # meta, opc/key/val
+            + [vmem()] * (2 * n_planes)           # lo + hi spans
+            + [anyspec()] * n_planes              # ring planes
+            + [state_spec, state_spec]            # values/present
+        )
         if fenced:
             in_specs.append(
                 pl.BlockSpec((1, tile_r), lambda i: (0, i))
             )
+        ring0 = 4 + 2 * n_planes
         return pl.pallas_call(
             kernel,
             grid=(sub_r // tile_r,),
             in_specs=in_specs,
-            out_specs=[
-                anyspec(), anyspec(),                 # ring planes out
+            out_specs=[anyspec()] * n_planes + [
                 state_spec, state_spec,
                 pl.BlockSpec((window, tile_r), lambda i: (0, i)),
             ],
-            out_shape=[
-                jax.ShapeDtypeStruct((rows, 128), jnp.int32),
-                jax.ShapeDtypeStruct((rows, 128, A), jnp.int32),
+            out_shape=[ring_shape] * n_planes + [
                 jax.ShapeDtypeStruct((kp, sub_r), jnp.int32),
                 jax.ShapeDtypeStruct((kp, sub_r), jnp.int32),
                 jax.ShapeDtypeStruct((window, sub_r), jnp.int32),
@@ -452,7 +444,7 @@ def make_fused_hashmap_calls(
             # UN-BLOCKED ring planes aliased in->out: outside the grid
             # pipeline, so exempt from the r5 blocked-plane rule (see
             # ops/pallas_ring.py and nrlint aliased-pallas-planes)
-            input_output_aliases={8: 0, 9: 1},
+            input_output_aliases={ring0 + i: i for i in range(n_planes)},
             scratch_shapes=[pltpu.SemaphoreType.DMA(())],
             interpret=interpret,
         )
@@ -552,21 +544,19 @@ class FusedHashmapEngine(FusedEngineHost):
         from node_replication_tpu.ops.pallas_ring import (
             append_window_planes,
             fused_cursor_lattice,
-            ring_rows,
+            ring_log,
+            ring_planes,
         )
 
         calls, chunk_r = self._built(window, fenced)
         spec = self.spec
-        R, A = spec.n_replicas, spec.arg_width
-        rows = ring_rows(spec.capacity)
+        R = spec.n_replicas
 
         def raw(log, vals_t, pres_t, opcodes, args, count,
                 fenced_vec=None):
-            ring_opc = log.opcodes.reshape(rows, 128)
-            ring_args = log.args.reshape(rows, 128, A)
-            s_lo, planes = append_window_planes(
-                spec.mask, ring_opc, ring_args, opcodes, args,
-                log.tail, count,
+            ring = ring_planes(log)
+            s_lo, lo, hi = append_window_planes(
+                spec.mask, ring, opcodes, args, log.tail, count,
             )
             meta = jnp.stack(
                 [s_lo, jnp.asarray(count, jnp.int32)]
@@ -577,17 +567,18 @@ class FusedHashmapEngine(FusedEngineHost):
                 None if fenced_vec is None
                 else jnp.asarray(fenced_vec, jnp.int32).reshape(1, R)
             )
+            n = len(ring)
             v_chunks, p_chunks, r_chunks = [], [], []
             with x64_disabled():
                 for r0 in range(0, R, chunk_r):
                     sub = min(chunk_r, R - r0)
-                    ins = [meta, opcodes, key, val, *planes,
-                           ring_opc, ring_args,
+                    ins = [meta, opcodes, key, val, *lo, *hi, *ring,
                            vals_t[:, r0:r0 + sub],
                            pres_t[:, r0:r0 + sub]]
                     if fen_plane is not None:
                         ins.append(fen_plane[:, r0:r0 + sub])
-                    (ring_opc, ring_args, v, p, r) = calls[sub](*ins)
+                    out = calls[sub](*ins)
+                    ring, (v, p, r) = tuple(out[:n]), out[n:]
                     v_chunks.append(v)
                     p_chunks.append(p)
                     r_chunks.append(r)
@@ -597,10 +588,7 @@ class FusedHashmapEngine(FusedEngineHost):
             )
             vals_t, pres_t = cat(v_chunks), cat(p_chunks)
             resps = cat(r_chunks)
-            log = log._replace(
-                opcodes=ring_opc.reshape(spec.capacity),
-                args=ring_args.reshape(spec.capacity, A),
-            )
+            log = ring_log(log, ring)
             log = fused_cursor_lattice(log, count, fenced_vec)
             return log, vals_t, pres_t, resps
 

@@ -672,8 +672,7 @@ def make_pallas_vspace_plan_step(
     merge, so fleet throughput grows ~linearly with R, where the classic
     grouped kernel is capped at G/450 ns by VMEM (G replicas per grid
     step) and the XLA plan pays ~19 µs/entry in sort/scatter passes
-    whose COMPILE time also grows with the window
-    (BENCH_NOTES r5). Same lock-step precondition as `core/step`'s
+    whose COMPILE time also grows with the window. Same lock-step precondition as `core/step`'s
     plan/merge path; differential suite:
     tests/test_pallas_vspace.py::TestPlanStep.
     """
@@ -899,27 +898,28 @@ def _vspace_reads(n_pages: int, max_span: int, radix: bool):
 
 # ------------------------------------------------- fused combiner round
 def _fused_flat_kernel(meta_ref, opc_ref, a0_ref, a1_ref, a2_ref,
-                       app_opc_lo, app_args_lo, app_opc_hi, app_args_hi,
-                       ring_opc_in, ring_args_in, fr_in,
-                       ring_opc_out, ring_args_out, fr_out, resp_ref,
-                       sem, *, n_pages: int, max_span: int, window: int,
-                       rows: int, span_rows: int, win_rows: int):
+                       *refs, n_planes: int, n_pages: int,
+                       max_span: int, window: int, rows: int,
+                       span_rows: int, win_rows: int):
     """Fused flat-vspace combiner round: the span-machinery replay body
     (`_flat_body` — unchanged, so the replay semantics cannot drift
     from the replay-only kernel) prefixed with the ring-window append
     DMA (`ops/pallas_ring.py`). One launch appends the batch to the
     ring AND replays it into every replica group."""
-    from node_replication_tpu.ops.pallas_ring import ring_append_dma
+    from node_replication_tpu.ops.pallas_ring import (
+        ring_append_dma,
+        split_ring_refs,
+    )
 
-    del ring_opc_in, ring_args_in  # content flows via the aliasing
+    # ring content flows via the aliasing; the ring inputs go unread
+    lo, hi, _ring_in, rest = split_ring_refs(refs, n_planes)
+    fr_in, *rest = rest
+    ring_out = rest[:n_planes]
+    fr_out, resp_ref, sem = rest[n_planes:]
     with x64_disabled():
         @pl.when(pl.program_id(0) == 0)
         def _append():
-            ring_append_dma(
-                sem, meta_ref[0], win_rows,
-                (app_opc_lo, app_args_lo), (app_opc_hi, app_args_hi),
-                (ring_opc_out, ring_args_out),
-            )
+            ring_append_dma(sem, meta_ref[0], win_rows, lo, hi, ring_out)
 
         _flat_body(opc_ref, a0_ref, a1_ref, a2_ref, fr_in, fr_out,
                    resp_ref, n_pages, max_span, window, rows,
@@ -1021,16 +1021,19 @@ class FusedVspaceEngine(FusedEngineHost):
         win = window_rows(window)
         nrr = ring_rows(spec.capacity)
         A = spec.arg_width
+        n_planes = 1 + A
         kernel = functools.partial(
-            _fused_flat_kernel, n_pages=self.n_pages,
-            max_span=self.max_span, window=window, rows=rows,
-            span_rows=span_rows, win_rows=win,
+            _fused_flat_kernel, n_planes=n_planes,
+            n_pages=self.n_pages, max_span=self.max_span,
+            window=window, rows=rows, span_rows=span_rows, win_rows=win,
         )
         smem = lambda: pl.BlockSpec(memory_space=pltpu.SMEM)
         anyspec = lambda: pl.BlockSpec(memory_space=pltpu.ANY)
         vmem = lambda: pl.BlockSpec(memory_space=pltpu.VMEM)
         shared = pl.BlockSpec((1, 1, window), lambda i: (0, 0, 0),
                               memory_space=pltpu.SMEM)
+        ring_shape = jax.ShapeDtypeStruct((nrr, 128), jnp.int32)
+        ring0 = 5 + 2 * n_planes
 
         def build_call(sub_r: int):
             state_spec = pl.BlockSpec((group, rows, 128),
@@ -1038,23 +1041,22 @@ class FusedVspaceEngine(FusedEngineHost):
             return pl.pallas_call(
                 kernel,
                 grid=(sub_r // group,),
-                in_specs=[
-                    smem(),                       # meta
-                    smem(), smem(), smem(), smem(),  # opc/a0/a1/a2
-                    vmem(), vmem(), vmem(), vmem(),  # append planes
-                    anyspec(), anyspec(),            # ring planes
-                    state_spec,
-                ],
-                out_specs=[anyspec(), anyspec(), state_spec, shared],
-                out_shape=[
-                    jax.ShapeDtypeStruct((nrr, 128), jnp.int32),
-                    jax.ShapeDtypeStruct((nrr, 128, A), jnp.int32),
+                in_specs=(
+                    [smem()] * 5                  # meta, opc/a0/a1/a2
+                    + [vmem()] * (2 * n_planes)   # lo + hi spans
+                    + [anyspec()] * n_planes      # ring planes
+                    + [state_spec]
+                ),
+                out_specs=[anyspec()] * n_planes + [state_spec, shared],
+                out_shape=[ring_shape] * n_planes + [
                     jax.ShapeDtypeStruct((sub_r, rows, 128), jnp.int32),
                     jax.ShapeDtypeStruct((1, 1, window), jnp.int32),
                 ],
                 # UN-BLOCKED ring planes aliased in->out (outside the
                 # grid pipeline — the r5-safe aliasing regime)
-                input_output_aliases={9: 0, 10: 1},
+                input_output_aliases={
+                    ring0 + i: i for i in range(n_planes)
+                },
                 scratch_shapes=[pltpu.SemaphoreType.DMA(())],
                 interpret=self.interpret,
             )
@@ -1066,7 +1068,8 @@ class FusedVspaceEngine(FusedEngineHost):
         from node_replication_tpu.ops.pallas_ring import (
             append_window_planes,
             fused_cursor_lattice,
-            ring_rows,
+            ring_log,
+            ring_planes,
         )
 
         if fenced:
@@ -1076,40 +1079,36 @@ class FusedVspaceEngine(FusedEngineHost):
             )
         calls, chunk_r = self._built(window)
         spec = self.spec
-        R, A, P = spec.n_replicas, spec.arg_width, self.n_pages
+        R, P = spec.n_replicas, self.n_pages
         rows = self._rows
-        nrr = ring_rows(spec.capacity)
 
         def fn(log, states, opcodes, args, count, fenced_vec=None):
-            ring_opc = log.opcodes.reshape(nrr, 128)
-            ring_args = log.args.reshape(nrr, 128, A)
-            s_lo, planes = append_window_planes(
-                spec.mask, ring_opc, ring_args, opcodes, args,
-                log.tail, count,
+            ring = ring_planes(log)
+            s_lo, lo, hi = append_window_planes(
+                spec.mask, ring, opcodes, args, log.tail, count,
             )
             meta = jnp.stack([s_lo, jnp.asarray(count, jnp.int32)])
             fr = jnp.zeros((R, rows * 128), jnp.int32).at[:, :P].set(
                 states["frames"]
             ).reshape(R, rows, 128)
             a0, a1, a2 = args[:, 0], args[:, 1], args[:, 2]
+            n = len(ring)
             fr_chunks = []
             resp = None
             with x64_disabled():
                 for r0 in range(0, R, chunk_r):
                     sub = min(chunk_r, R - r0)
-                    ring_opc, ring_args, f, resp = calls[sub](
-                        meta, opcodes, a0, a1, a2, *planes,
-                        ring_opc, ring_args, fr[r0:r0 + sub],
+                    out = calls[sub](
+                        meta, opcodes, a0, a1, a2, *lo, *hi, *ring,
+                        fr[r0:r0 + sub],
                     )
+                    ring, (f, resp) = tuple(out[:n]), out[n:]
                     fr_chunks.append(f)
             fr = (
                 fr_chunks[0] if len(fr_chunks) == 1
                 else jnp.concatenate(fr_chunks, axis=0)
             )
-            log = log._replace(
-                opcodes=ring_opc.reshape(spec.capacity),
-                args=ring_args.reshape(spec.capacity, A),
-            )
+            log = ring_log(log, ring)
             log = fused_cursor_lattice(log, count, None)
             states = {"frames": fr.reshape(R, -1)[:, :P]}
             # canonical responses, shared by the lock-step fleet

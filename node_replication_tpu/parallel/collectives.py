@@ -118,8 +118,8 @@ def make_shmap_step(
         local_min = jnp.min(new_ltails_l)
         log = log._replace(
             ltails=new_ltails_l,
-            ctail=jnp.maximum(log.ctail, lax.pmax(local_max, axis)),
-            head=lax.pmin(local_min, axis),
+            ctail=jnp.maximum(log.ctail, pmax64(local_max, axis)),
+            head=pmin64(local_min, axis),
         )
         # own responses: local replica r sits at global index
         # didx*Rl + r; its writes occupy window offsets [g*Bw, (g+1)*Bw)
@@ -151,6 +151,19 @@ def make_shmap_step(
     return jax.jit(fn)
 
 
+def pmin64(v, axis: str):
+    """Min of an int64 cursor over the mesh axis. The v5e compiler
+    lowers only Sum all-reduces of 64-bit values (`lax.pmin` on int64
+    is refused), so the per-shard scalars are all-gathered and reduced
+    locally — exact, and a few bytes per shard."""
+    return jnp.min(lax.all_gather(v, axis))
+
+
+def pmax64(v, axis: str):
+    """Max twin of `pmin64`."""
+    return jnp.max(lax.all_gather(v, axis))
+
+
 def _cursor_lattice_join(log, new_lt, fenced_mask, reduce_min,
                          reduce_max):
     """The cross-shard half of the exec-round cursor lattice — ONE
@@ -167,7 +180,7 @@ def _cursor_lattice_join(log, new_lt, fenced_mask, reduce_min,
       construction like `core/log._gc_head`).
 
     `reduce_min`/`reduce_max` close over the cross-shard reduction:
-    `lax.pmin`/`lax.pmax` over ICI inside a shard_map local, the
+    `pmin64`/`pmax64` over ICI inside a shard_map local, the
     identity for host-side joins over already-concatenated cursors
     (`MeshFusedEngine._sliced_round`). Returns `log` with
     ctail/head replaced (the caller installs `ltails`)."""
@@ -252,7 +265,7 @@ def make_shmap_exec(
         # composed via _FAR — the one shared lattice-join definition)
         log = _cursor_lattice_join(
             log, new_lt, fenced_l,
-            lambda v: lax.pmin(v, axis), lambda v: lax.pmax(v, axis),
+            lambda v: pmin64(v, axis), lambda v: pmax64(v, axis),
         )
         log = log._replace(ltails=new_lt)
         return log, states_l, resps_l
@@ -510,8 +523,8 @@ class MeshFusedEngine(FusedEngineHost):
             # ctail) — the same shared join as make_shmap_exec
             log = _cursor_lattice_join(
                 log, log.ltails, fen_l,
-                lambda v: lax.pmin(v, axis),
-                lambda v: lax.pmax(v, axis),
+                lambda v: pmin64(v, axis),
+                lambda v: pmax64(v, axis),
             )
             return log, states_l, resps_l
 

@@ -1,42 +1,18 @@
-"""JAX version-compat shims (0.4.x <-> 0.5+ API moves).
+"""The two JAX names the package routes through one place (jax 0.9).
 
-The seed targeted a newer jax surface; the pinned container runs jax
-0.4.x. Two APIs moved between those lines and broke 21 tier-1 tests at
-the seed (every `tests/test_pallas*` and `tests/test_collectives.py`
-failure — see BENCH_NOTES.md triage):
-
-- `jax.enable_x64(False)` (0.5+ parametrized context manager) vs
-  `jax.experimental.disable_x64()` (0.4.x): used by the pallas kernels
-  to trace pure-int32 programs under the package's global x64 mode.
-- `jax.shard_map` (0.5+) vs `jax.experimental.shard_map.shard_map`
-  (0.4.x): the explicit-collective multi-chip step.
-
-Import from here; never touch the moved names directly.
+- `shard_map`: the explicit-collective multi-chip step
+  (`parallel/collectives.py`).
+- `x64_disabled()`: the pallas kernels trace pure-int32 programs while
+  the package enables x64 globally.
 """
 
 from __future__ import annotations
 
 import jax
 
-try:  # jax >= 0.5
-    shard_map = jax.shard_map
-except AttributeError:  # jax 0.4.x: experimental home + check_rep kwarg
-    import functools
-
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-
-    @functools.wraps(_shard_map_04)
-    def shard_map(*args, **kwargs):
-        if "check_vma" in kwargs:  # 0.5+ renamed check_rep -> check_vma
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_04(*args, **kwargs)
+shard_map = jax.shard_map
 
 
 def x64_disabled():
-    """Context manager: trace with x64 disabled (pallas kernels build
-    pure-int32 programs while the package globally enables x64)."""
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64(False)
-    from jax.experimental import disable_x64
-
-    return disable_x64()
+    """Context manager: trace with x64 disabled."""
+    return jax.enable_x64(False)

@@ -133,9 +133,7 @@ class TestHardwareSmoke:
         os.environ.get("NR_TPU_SMOKE") != "1",
         reason="set NR_TPU_SMOKE=1 to run the non-interpret Mosaic "
                "lowering on real TPU hardware (needs the chip; the suite "
-               "itself runs on forced-CPU). Proven r3 on TPU v5e: "
-               "bench.py --pallas --keys 1024 = 1.22G dispatches/s vs "
-               "13.0M for the generic scan at the same config.",
+               "itself runs on forced-CPU)",
     )
     def test_kernel_compiles_and_runs_on_tpu(self):
         # subprocess: the suite's conftest forces jax_platforms=cpu, so

@@ -348,6 +348,36 @@ class TestAutoWinnerSelection:
             assert fused_c.value == mark_fused
             assert nr.stats()["exec_rounds"] > mark_exec
 
+    def test_kernel_compile_error_surfaces(self, monkeypatch):
+        # a Mosaic refusal at the fused round's compile is an error,
+        # not a shape rejection: it must reach the caller and leave
+        # the tier calibrating — never a silent switch to the chain
+        from jax._src.pallas.mosaic.error_handling import MosaicError
+
+        from node_replication_tpu.ops.pallas_replay import (
+            FusedHashmapEngine,
+        )
+
+        def refused(self, window, fenced=False):
+            def fn(*args):
+                raise MosaicError("Mosaic failed to compile TPU kernel")
+            return fn
+
+        monkeypatch.setenv("NR_TPU_FUSED_CAL", "1")
+        monkeypatch.setattr(FusedHashmapEngine, "round_fn", refused)
+        fb = get_registry().counter("nr.exec.engine.fused_fallback")
+        nr = NodeReplicated(make_hashmap(17), n_replicas=2,
+                            log_entries=512, gc_slack=64, engine="auto")
+        t = nr.register(0)
+        with pytest.raises(MosaicError):
+            for i in range(8):  # chain samples first, then the kernel
+                nr.execute_mut((1, i, i), t)
+        before = fb.value
+        assert nr.stats()["fused_tier"] == "calibrating"
+        with pytest.raises(MosaicError):
+            nr.execute_mut((1, 9, 9), t)
+        assert fb.value == before
+
     def test_samples_are_per_window(self, monkeypatch):
         # chain/fused timings only compare at the SAME padded window
         # (and the same fence mask — the key's second half): a

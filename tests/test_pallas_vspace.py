@@ -275,9 +275,8 @@ class TestPlanStep:
 
 @pytest.mark.skipif(
     not os.environ.get("NR_TPU_SMOKE"),
-    reason="hardware smoke (set NR_TPU_SMOKE=1 on a real TPU). Proven r4 "
-           "on TPU v5e: long-log R=4 full step 4.7 ms -> 3.48M disp/s vs "
-           "0.021M for the generic scan (~166x) at the identical config.",
+    reason="hardware smoke (set NR_TPU_SMOKE=1 on a real TPU; the "
+           "suite itself runs on the CPU)",
 )
 class TestHardwareSmoke:
     def test_radix_kernel_on_device(self):
