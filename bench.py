@@ -4726,8 +4726,8 @@ def main():
         fence(log, states, out)
         return log, states
 
-    from node_replication_tpu.utils.trace import get_tracer
-    from node_replication_tpu.utils.trace import span as trace_span
+    from node_replication_tpu.obs.recorder import get_tracer
+    from node_replication_tpu.obs.recorder import span as trace_span
 
     per_step = R * span + R * Br  # executed dispatches per step
 

@@ -60,7 +60,7 @@ from node_replication_tpu.core.log import (
 from node_replication_tpu.core.replica import replicate_state
 from node_replication_tpu.obs.metrics import get_registry
 from node_replication_tpu.ops.encoding import Dispatch
-from node_replication_tpu.utils.trace import span
+from node_replication_tpu.obs.recorder import span
 
 PyTree = Any
 

@@ -55,7 +55,7 @@ from node_replication_tpu.core.replica import (
 from node_replication_tpu.fault.inject import fault_hook
 from node_replication_tpu.obs.metrics import COUNT_BUCKETS, get_registry
 from node_replication_tpu.ops.encoding import Dispatch, apply_read, encode_ops
-from node_replication_tpu.utils.trace import get_tracer, span
+from node_replication_tpu.obs.recorder import get_tracer, span
 
 logger = logging.getLogger("node_replication_tpu")
 

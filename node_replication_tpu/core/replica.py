@@ -70,7 +70,7 @@ from node_replication_tpu.ops.encoding import (
     apply_read,
     encode_ops,
 )
-from node_replication_tpu.utils.trace import get_tracer, span
+from node_replication_tpu.obs.recorder import PHASE_PREFIX, get_tracer, span
 
 logger = logging.getLogger("node_replication_tpu")
 
@@ -239,25 +239,84 @@ def _locked(fn):
     fast path adds one `acquire(blocking=False)` either way, which an
     RLock satisfies reentrantly.
     """
-    reg = get_registry()
-    m_wait = reg.histogram("nr.lock.wait_s")
+    return _locked_phase(None)(fn)
 
-    @functools.wraps(fn)
-    def inner(self, *args, **kwargs):
-        lock = self._lock
-        if not reg.enabled:
-            with lock:
-                return fn(self, *args, **kwargs)
-        if not lock.acquire(blocking=False):
+
+def _locked_phase(phase: str | None, fields=None):
+    """`_locked`, and for the entry points whose lock time the serve
+    path pays: with metrics on, the thread's outermost acquisition is
+    split into two spans (`obs/recorder.span`), `<phase>.lock_wait`
+    from the call to the acquisition and `<phase>.hold` from the
+    acquisition to the release — histograms `nr.<phase>.lock_wait_s`
+    and `nr.<phase>.hold_s`, and profiler annotations of those names
+    carrying `fields(*args, **kwargs)` (the join keys `rid`, `n`,
+    `pos0`). An uncontended acquisition observes a wait of 0 and opens
+    no wait span, so the wait's count is the number of calls. A nested
+    call (the lock is reentrant) records no phase, so
+    `execute_mut_batch` counts once, not once more for each of
+    `begin_mut_batch` and `finish_mut_batch`; the split protocol
+    itself takes the lock twice a round."""
+
+    def deco(fn):
+        reg = get_registry()
+        m_wait = reg.histogram("nr.lock.wait_s")
+        if phase is not None:
+            wait_event, hold_event = f"{phase}.lock_wait", f"{phase}.hold"
+            m_phase_wait = reg.histogram(f"{PHASE_PREFIX}{wait_event}_s")
+
+        def wait_for(lock) -> None:
             t0 = time.monotonic()
             lock.acquire()
             m_wait.observe(time.monotonic() - t0)
-        try:
-            return fn(self, *args, **kwargs)
-        finally:
-            lock.release()
 
-    return inner
+        @functools.wraps(fn)
+        def inner(self, *args, **kwargs):
+            lock = self._lock
+            if not reg.enabled:
+                with lock:
+                    return fn(self, *args, **kwargs)
+            if phase is None or lock._is_owned():
+                if not lock.acquire(blocking=False):
+                    wait_for(lock)
+                try:
+                    return fn(self, *args, **kwargs)
+                finally:
+                    lock.release()
+            f = fields(*args, **kwargs)
+            if lock.acquire(blocking=False):
+                m_phase_wait.observe(0.0)
+            else:
+                with span(wait_event, **f):
+                    wait_for(lock)
+            try:
+                with span(hold_event, **f):
+                    return fn(self, *args, **kwargs)
+            finally:
+                lock.release()
+
+        return inner
+
+    return deco
+
+
+def _batch_fields(ops, rid=0) -> dict:
+    return {"rid": int(rid), "n": len(ops)}
+
+
+def _pending_fields(pending) -> dict:
+    return {"rid": pending.rid, "n": pending.n, "pos0": pending.pos0}
+
+
+def _read_fields(op, token, *_, **__) -> dict:
+    return {"rid": token.rid}
+
+
+def _named(fn, name: str):
+    """`fn` under `name`. `jax.jit` names its program `jit_<name>`, the
+    module every op of it shows under in a profiler trace; a bare
+    `partial` has no name and would show as `jit__unknown`."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 def replicate_state(state, n_replicas: int):
@@ -875,35 +934,36 @@ class NodeReplicated(_FusedTier):
             return exec_fn(self.spec, dispatch, log, states,
                            window=window, fenced=fenced)
 
+        exec_prog = _named(partial(exec_fn, self.spec, dispatch), "log_exec")
+        fenced_prog = _named(_exec_fenced, "log_exec_fenced")
+        append_prog = _named(partial(log_append, self.spec), "log_append")
         if self.debug:
             from node_replication_tpu.utils.checks import checked
 
             self._exec_jit = jax.jit(
-                checked(partial(exec_fn, self.spec, dispatch)),
+                _named(checked(exec_prog), "log_exec_checked"),
                 static_argnames=("window",),
             )
             self._exec_fenced_jit = jax.jit(
-                checked(_exec_fenced), static_argnames=("window",),
+                _named(checked(fenced_prog), "log_exec_fenced_checked"),
+                static_argnames=("window",),
             )
             self._append_jit = jax.jit(
-                checked(partial(log_append, self.spec))
+                _named(checked(append_prog), "log_append_checked")
             )
         else:
             self._exec_jit = jax.jit(
-                partial(exec_fn, self.spec, dispatch),
-                static_argnames=("window",),
+                exec_prog, static_argnames=("window",),
                 donate_argnums=(0, 1),
             )
             # Fenced twin of the exec program (compiled only if a
             # replica is ever fenced — jit compilation is lazy, so the
             # fault-free path never pays for it).
             self._exec_fenced_jit = jax.jit(
-                _exec_fenced, static_argnames=("window",),
+                fenced_prog, static_argnames=("window",),
                 donate_argnums=(0, 1),
             )
-            self._append_jit = jax.jit(
-                partial(log_append, self.spec), donate_argnums=(0,)
-            )
+            self._append_jit = jax.jit(append_prog, donate_argnums=(0,))
 
         if self.mesh is not None and self._mesh_tier == "shmap":
             # the explicit-collective tier REPLACES the exec programs
@@ -1243,7 +1303,7 @@ class NodeReplicated(_FusedTier):
             r = ctx.res()
         return out
 
-    @_locked
+    @_locked_phase("read", _read_fields)
     def execute(self, op: tuple, token: ReplicaToken):
         """Read path (`Replica::execute` → `read_only`,
         `nr/src/replica.rs:404-410`, `483-497`): wait until this replica has
@@ -1289,7 +1349,7 @@ class NodeReplicated(_FusedTier):
         ctail = int(self.log.ctail)
         return max(0, ctail - int(np.asarray(self.log.ltails)[rid]))
 
-    @_locked
+    @_locked_phase("read", _read_fields)
     def execute_stale(self, op: tuple, token: ReplicaToken):
         """Bounded-staleness read: dispatch against this replica's
         CURRENT state with NO read-sync — the on-primary analog of the
@@ -1305,7 +1365,7 @@ class NodeReplicated(_FusedTier):
             raise ReplicaFencedError(rid)
         return self._dispatch_read(rid, op)
 
-    @_locked
+    @_locked_phase("read", _read_fields)
     def execute_stale_bounded(self, op: tuple, token: ReplicaToken,
                               max_lag: int):
         """`execute_stale` with the staleness bound enforced ATOMICALLY:
@@ -1593,7 +1653,7 @@ class NodeReplicated(_FusedTier):
         )
         self._contexts[(rid, BATCH_TID)].reset()
 
-    @_locked
+    @_locked_phase("round", _batch_fields)
     def begin_mut_batch(self, ops: list[tuple],
                         rid: int = 0) -> _PendingRound:
         """Split-round batch entry, first half (the serve pipeline's
@@ -1649,7 +1709,7 @@ class NodeReplicated(_FusedTier):
         self._pending_batch[rid] = pending
         return pending
 
-    @_locked
+    @_locked_phase("round", _pending_fields)
     def finish_mut_batch(self, pending: _PendingRound) -> list:
         """Split-round batch entry, second half (the serve pipeline's
         completion stage): replay to the round's target (or read back
@@ -1693,7 +1753,7 @@ class NodeReplicated(_FusedTier):
         pending.fused_resps = None
         self._drop_batch_inflight(rid)
 
-    @_locked
+    @_locked_phase("round", _batch_fields)
     def execute_mut_batch(self, ops: list[tuple],
                           rid: int = 0) -> list:
         """Execute a caller-assembled batch of write ops as ONE
@@ -1787,7 +1847,7 @@ class NodeReplicated(_FusedTier):
         W = chunk * shards
         if self._ring_gather is None:
             self._ring_gather = jax.jit(
-                partial(gather_window, self.spec),
+                _named(partial(gather_window, self.spec), "ring_gather"),
                 static_argnames=("window",),
             )
         opc, args = self._ring_gather(self.log.opcodes, self.log.args,

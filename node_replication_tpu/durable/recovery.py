@@ -48,7 +48,7 @@ from node_replication_tpu.core.replica import NodeReplicated
 from node_replication_tpu.durable.wal import WalError, WriteAheadLog
 from node_replication_tpu.obs.metrics import get_registry
 from node_replication_tpu.utils.clock import get_clock
-from node_replication_tpu.utils.trace import get_tracer, span
+from node_replication_tpu.obs.recorder import get_tracer, span
 
 _SNAP_RE = re.compile(r"^snap-(\d{20})\.npz$")
 

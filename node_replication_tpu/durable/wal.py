@@ -82,7 +82,7 @@ import numpy as np
 from node_replication_tpu.fault.inject import fault_hook
 from node_replication_tpu.obs.metrics import get_registry
 from node_replication_tpu.utils.clock import get_clock
-from node_replication_tpu.utils.trace import get_tracer
+from node_replication_tpu.obs.recorder import get_tracer
 
 _MAGIC = b"NRWAL001"
 _SEG_HEADER = struct.Struct("<8sqi")  # magic, base pos, arg_width

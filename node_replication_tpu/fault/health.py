@@ -46,7 +46,7 @@ import numpy as np
 
 from node_replication_tpu.obs.metrics import get_registry
 from node_replication_tpu.utils.clock import get_clock
-from node_replication_tpu.utils.trace import get_tracer
+from node_replication_tpu.obs.recorder import get_tracer
 
 HEALTHY = "healthy"
 SUSPECT = "suspect"

@@ -57,7 +57,7 @@ from node_replication_tpu.analysis.locks import make_lock
 
 from node_replication_tpu.obs.metrics import get_registry
 from node_replication_tpu.utils.clock import get_clock
-from node_replication_tpu.utils.trace import get_tracer
+from node_replication_tpu.obs.recorder import get_tracer
 
 # Every armable site, in hook order of the write path; the `wal-*`
 # sites are the durability plane's choke points (`durable/wal.py`:

@@ -52,7 +52,7 @@ from node_replication_tpu.fault.health import (
 )
 from node_replication_tpu.obs.metrics import get_registry
 from node_replication_tpu.utils.clock import get_clock
-from node_replication_tpu.utils.trace import get_tracer
+from node_replication_tpu.obs.recorder import get_tracer
 
 logger = logging.getLogger("node_replication_tpu")
 

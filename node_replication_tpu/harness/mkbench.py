@@ -44,7 +44,7 @@ from node_replication_tpu.harness.workloads import (
     generate_batches,
     split_write_read,
 )
-from node_replication_tpu.utils.trace import get_tracer
+from node_replication_tpu.obs.recorder import get_tracer
 
 SCALEOUT_CSV = "scaleout_benchmarks.csv"
 SKEW_CSV = "cnr_skew_stats.csv"

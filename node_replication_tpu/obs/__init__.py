@@ -7,8 +7,9 @@ Single-process half (PR 1 lineage):
 - `obs.recorder` — the `Tracer` flight recorder and `span` timing
   context (enable with NR_TPU_TRACE=<path|mem>; fence-accurate spans
   with NR_TPU_TRACE_FENCE=1; per-record sampling with
-  NR_TPU_TRACE_SAMPLE=1/N). `utils/trace.py` re-exports these for
-  backward compatibility.
+  NR_TPU_TRACE_SAMPLE=1/N). With the metrics registry on, every span
+  is also a profiler annotation `nr.<event>` and a histogram
+  `nr.<event>_s`.
 - `obs.report` — trace-report CLI:
   `python -m node_replication_tpu.obs.report trace.jsonl [--json]`.
 

@@ -23,15 +23,29 @@ and thread-safe.
 Enable with `NR_TPU_METRICS=1` or `get_registry().enable()`. `snapshot()`
 returns a plain-dict view suitable for JSON (`NodeReplicated.snapshot()`
 and `MultiLogReplicated.snapshot()` embed it).
+
+The first enable of the process-wide registry also installs two hooks on
+what stalls the whole process (`_install_process_hooks`), each inert
+while the registry is off: a `jax.monitoring` listener that counts jaxpr
+traces (`nr.compile.traces`) and times backend compiles
+(`nr.compile.backend_s`), and a `gc.callbacks` entry that times each
+collection (`py.gc.pause_s`) and spans it with a profiler annotation
+`nr.gc`, so a pause lines up with the device trace.
 """
 
 from __future__ import annotations
 
 import bisect
+import gc
 import os
 import threading
+import time
 
 from node_replication_tpu.analysis.locks import make_lock
+
+# `jax.monitoring` duration events (jax/_src/dispatch.py)
+JAXPR_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 # Default histogram buckets for durations in seconds: 1us .. ~100s,
 # roughly x4 per step (14 buckets; small enough to snapshot cheaply).
@@ -212,15 +226,23 @@ class Histogram:
 class MetricsRegistry:
     """Named instruments behind one process-wide enable flag."""
 
-    def __init__(self, enabled: bool = False):
+    def __init__(self, enabled: bool = False, on_first_enable=None):
         # nrcheck: lock-order MetricsRegistry._lock -> Counter._lock — reset() zeroes instruments under the registry lock
         # nrcheck: lock-order MetricsRegistry._lock -> Histogram._lock — reset() zeroes instruments under the registry lock
         self._lock = make_lock("MetricsRegistry._lock")
         self._metrics: dict[str, Counter | Gauge | Histogram] = {}
-        self.enabled = bool(enabled)
+        self.enabled = False
+        #: called once with the registry the first time it is enabled
+        self._on_first_enable = on_first_enable
+        if enabled:
+            self.enable()
 
     def enable(self) -> None:
         self.enabled = True
+        with self._lock:
+            hook, self._on_first_enable = self._on_first_enable, None
+        if hook is not None:
+            hook(self)
 
     def disable(self) -> None:
         self.enabled = False
@@ -306,8 +328,57 @@ class MetricsRegistry:
         return out
 
 
+class _GcPauses:
+    """`gc.callbacks` entry: each collection's pause into `hist`, and an
+    `nr.gc` profiler annotation from its start to its stop. Collections
+    never overlap (the interpreter runs one at a time), so one pending
+    start suffices; a start seen with the registry off is not timed."""
+
+    def __init__(self, reg: "MetricsRegistry", profiler):
+        self._reg = reg
+        self._hist = reg.histogram("py.gc.pause_s")
+        self._profiler = profiler
+        self._t0: float | None = None
+        self._ann = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            if not self._reg.enabled:
+                return
+            self._ann = self._profiler.TraceAnnotation(
+                "nr.gc", generation=info.get("generation", -1))
+            self._ann.__enter__()
+            self._t0 = time.perf_counter()
+        elif self._t0 is not None:
+            self._hist.observe(time.perf_counter() - self._t0)
+            self._ann.__exit__(None, None, None)
+            self._t0 = self._ann = None
+
+
+def _install_process_hooks(reg: "MetricsRegistry") -> None:
+    """The compile listener and the GC timer of the module docstring;
+    installed once, on the process-wide registry's first enable."""
+    import jax.monitoring
+    import jax.profiler
+
+    traces = reg.counter("nr.compile.traces")
+    backend = reg.histogram("nr.compile.backend_s")
+
+    def on_duration(event: str, duration: float, **_kw) -> None:
+        if not reg.enabled:
+            return
+        if event == JAXPR_TRACE_EVENT:
+            traces.inc()
+        elif event == BACKEND_COMPILE_EVENT:
+            backend.observe(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    gc.callbacks.append(_GcPauses(reg, jax.profiler))
+
+
 _registry = MetricsRegistry(
-    enabled=os.environ.get("NR_TPU_METRICS", "") == "1"
+    enabled=os.environ.get("NR_TPU_METRICS", "") == "1",
+    on_first_enable=_install_process_hooks,
 )
 
 

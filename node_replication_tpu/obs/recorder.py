@@ -36,6 +36,14 @@ no-op singleton without reading the clock or allocating an event record
 (file), `NR_TPU_TRACE=mem` (in-memory; bound it with
 `NR_TPU_TRACE_RING=<n>`), or `get_tracer().enable(...)`.
 
+Phases: the metrics registry (`obs/metrics.py`, `NR_TPU_METRICS=1` or
+`get_registry().enable()`) is a second switch on the same spans. While
+it is on, `span("x", **fields)` also opens
+`jax.profiler.TraceAnnotation("nr.x", **fields)`, which a running JAX
+profiler records on its host plane, on the device trace's clock, and
+observes its duration (`time.perf_counter`) into the histogram `nr.x_s`.
+The JSONL event depends on the tracer alone, as before.
+
 Per-record sampling (`NR_TPU_TRACE_SAMPLE=1/N` or `=N`): the fleet
 trace plane (`obs/export.py` / `obs/collect.py`) joins events across
 processes on a record's log position `pos`, so per-record hop events
@@ -53,11 +61,14 @@ import collections
 import contextlib
 import json
 import os
-import threading
-
-from node_replication_tpu.analysis.locks import make_lock
 import time
 from typing import Any
+
+from node_replication_tpu.analysis.locks import make_lock
+from node_replication_tpu.obs.metrics import get_registry
+
+#: prefix of a span's profiler annotation and registry histogram
+PHASE_PREFIX = "nr."
 
 
 class _Span:
@@ -141,7 +152,7 @@ class Tracer:
         # racy-but-benign fast path: one word read; worst case one
         # event races an enable/disable
         # nrlint: disable=lock-discipline
-        if not self.enabled:
+        if not self.enabled:  # nrcheck: unshared — racy-but-benign one-word read
             return
         rec = {
             "ts": time.time(),  # nrlint: disable=wall-clock-time — correlation field; `mono` below is the ordering clock
@@ -242,33 +253,69 @@ def pos_sampled(pos: int) -> bool:
     return _sample_n <= 1 or int(pos) % _sample_n == 0
 
 
-@contextlib.contextmanager
+class _NullContext:
+    """Shared context of a span with both switches off."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> _NullSpan:
+        return _NULL_SPAN
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NULL_CONTEXT = _NullContext()
+_registry = get_registry()
+
+
 def span(event: str, **fields: Any):
     """Time a section; emits `<event>` with `duration_s` on exit.
 
     Yields a `_Span`: call `sp.add(...)` for fields only known inside the
     section and `sp.fence(*pytrees)` to make the span fence device work
     before the end timestamp under `NR_TPU_TRACE_FENCE=1` (see module
-    docstring). Disabled tracer: yields a shared no-op span, reads no
-    clock, allocates no record.
+    docstring). With the registry on, the section is also the phase
+    `nr.<event>`: a profiler annotation carrying `fields`, and a
+    histogram of its durations. Tracer and registry both off: returns a
+    shared no-op context, reads no clock, allocates no record.
     """
+    if not (_tracer.enabled or _registry.enabled):
+        return _NULL_CONTEXT
+    return _live_span(event, fields)
+
+
+def _annotation(name: str, fields: dict):
+    # deferred: importing this module must not import jax
+    import jax.profiler
+
+    return jax.profiler.TraceAnnotation(name, **fields)
+
+
+@contextlib.contextmanager
+def _live_span(event: str, fields: dict):
     t = _tracer
-    if not t.enabled:
-        yield _NULL_SPAN
-        return
+    log = t.enabled
+    ann = None
+    if _registry.enabled:
+        ann = _annotation(PHASE_PREFIX + event, fields)
+        ann.__enter__()
     sp = _Span()
     t0 = time.perf_counter()
     try:
         yield sp
     finally:
         fenced = False
-        if t.fence_spans and sp.fence_args is not None:
-            # import at call time: utils.fence pulls in jax, and the
-            # utils package __init__ imports this module back
+        if log and t.fence_spans and sp.fence_args is not None:
+            # import at call time: utils.fence pulls in jax
             from node_replication_tpu.utils.fence import fence
 
             fence(*sp.fence_args)
             fenced = True
         dur = time.perf_counter() - t0
-        t.emit(event, duration_s=dur, fenced=fenced, **fields,
-               **sp.fields)
+        if ann is not None:
+            ann.__exit__(None, None, None)
+            _registry.histogram(PHASE_PREFIX + event + "_s").observe(dur)
+        if log:
+            t.emit(event, duration_s=dur, fenced=fenced, **fields,
+                   **sp.fields)

@@ -222,7 +222,7 @@ class FusedEngineHost:
         best available truth; the bench's chain runners, whose
         dispatches ARE host calls, count at the call sites instead)."""
         from node_replication_tpu.core import log as _corelog
-        from node_replication_tpu.utils.trace import get_tracer
+        from node_replication_tpu.obs.recorder import get_tracer
 
         n_launch = self.launches(window)
         if self.tier == "mesh_fused":

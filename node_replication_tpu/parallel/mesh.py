@@ -168,7 +168,7 @@ def announce_placement(mesh: Mesh, n_replicas: int, wrapper: str,
     (per-device replica count, device count) and one `mesh-place`
     trace event — the report CLI's Mesh section feeds on these."""
     from node_replication_tpu.obs.metrics import get_registry
-    from node_replication_tpu.utils.trace import get_tracer
+    from node_replication_tpu.obs.recorder import get_tracer
 
     n_shards = int(np.prod(mesh.devices.shape))
     per_device = n_replicas // max(1, mesh.shape.get("replica", 1))

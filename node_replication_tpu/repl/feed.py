@@ -67,7 +67,7 @@ import numpy as np
 
 from node_replication_tpu.durable.wal import durable_publish
 from node_replication_tpu.obs.metrics import get_registry
-from node_replication_tpu.utils.trace import get_tracer
+from node_replication_tpu.obs.recorder import get_tracer
 
 _MSG_RE = re.compile(r"^rec-(\d{20})\.msg$")
 _MSG_HEADER = struct.Struct("<II")  # payload length, crc32(payload)

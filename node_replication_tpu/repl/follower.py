@@ -61,7 +61,7 @@ from node_replication_tpu.repl.feed import FeedGapError
 from node_replication_tpu.serve.errors import StaleRead
 from node_replication_tpu.serve.frontend import ServeConfig, ServeFrontend
 from node_replication_tpu.utils.clock import get_clock
-from node_replication_tpu.utils.trace import get_tracer, pos_sampled, span
+from node_replication_tpu.obs.recorder import get_tracer, pos_sampled, span
 
 logger = logging.getLogger("node_replication_tpu")
 

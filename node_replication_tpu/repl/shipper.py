@@ -45,7 +45,7 @@ from node_replication_tpu.analysis.locks import (
 from node_replication_tpu.fault.inject import fault_hook
 from node_replication_tpu.obs.metrics import get_registry
 from node_replication_tpu.utils.clock import get_clock
-from node_replication_tpu.utils.trace import get_tracer, pos_sampled
+from node_replication_tpu.obs.recorder import get_tracer, pos_sampled
 
 logger = logging.getLogger("node_replication_tpu")
 

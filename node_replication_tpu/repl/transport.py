@@ -89,7 +89,7 @@ from node_replication_tpu.repl.feed import (
     MAX_PAYLOAD_BYTES,
 )
 from node_replication_tpu.utils.clock import get_clock
-from node_replication_tpu.utils.trace import get_tracer, pos_sampled
+from node_replication_tpu.obs.recorder import get_tracer, pos_sampled
 
 logger = logging.getLogger("node_replication_tpu")
 

@@ -108,7 +108,7 @@ from node_replication_tpu.serve.overload import (
     OverloadGovernor,
 )
 from node_replication_tpu.utils.clock import get_clock
-from node_replication_tpu.utils.trace import get_tracer
+from node_replication_tpu.obs.recorder import get_tracer
 
 logger = logging.getLogger("node_replication_tpu")
 
@@ -439,18 +439,16 @@ class _SubmissionQueue:
     __slots__ = ("_lock", "_items", "_depth", "_closed", "_in_service",
                  "accepted", "shed", "completed", "deadline_missed",
                  "evicted", "shed_by_prio", "priority_inversions",
-                 "_reg", "_m_wait", "_m_linger")
+                 "_reg", "_m_wait")
 
     def __init__(self, depth: int):
         self._lock = make_condition("_SubmissionQueue._lock")
         # queue-wait accounting (host-budget input): how long the
-        # worker sat on the condition before the first op arrived, and
-        # how long it lingered for the batch to fill. One `enabled`
-        # branch per take_batch when metrics are off (obs/metrics.py
-        # cost rule); handles are created once, not per call.
+        # worker sat on the condition before the first op arrived. One
+        # `enabled` branch per take_batch when metrics are off
+        # (obs/metrics.py cost rule); the handle is created once.
         self._reg = get_registry()
         self._m_wait = self._reg.histogram("serve.queue.wait_s")
-        self._m_linger = self._reg.histogram("serve.queue.linger_s")
         self._items: tuple[deque[_Request], ...] = tuple(
             deque() for _ in PRIORITIES
         )
@@ -581,12 +579,6 @@ class _SubmissionQueue:
                     if rem <= 0:
                         break
                     clock.wait(self._lock, rem)
-                if self._reg.enabled:
-                    # t_end - linger_s is the linger start; no extra
-                    # clock call was spent on the disabled path
-                    self._m_linger.observe(
-                        clock.now() - (t_end - linger_s)
-                    )
             batch: list[_Request] = []
             for d in self._items:
                 while d and len(batch) < max_ops:
@@ -799,7 +791,7 @@ class ServeFrontend:
         #: every subtree already holds the bytes.
         self.ack_barrier: Callable[[int], None] | None = None
 
-        reg = get_registry()
+        reg = self._reg = get_registry()
         self._m_submitted = reg.counter("serve.submitted")
         self._m_completed = reg.counter("serve.completed")
         self._m_shed = reg.counter("serve.shed")
@@ -810,6 +802,8 @@ class ServeFrontend:
                                            buckets=COUNT_BUCKETS)
         self._m_batch_dur = reg.histogram("serve.batch.duration_s")
         self._m_req_lat = reg.histogram("serve.request.latency_s")
+        # each served request's wait from submit to its batch's assembly
+        self._m_req_queue = reg.histogram("serve.request.queue_s")
         # requests that expired while their round was in flight and
         # still resolved successfully (the completion-stage second
         # sweep): delivered — first resolution wins, the op executed —
@@ -1563,6 +1557,9 @@ class ServeFrontend:
                 )
             else:
                 live.append(req)
+        if self._reg.enabled:
+            for req in live:
+                self._m_req_queue.observe(now - req.future.t_submit)
         if missed:
             self._m_miss.inc(missed)
             get_tracer().emit("serve-deadline-miss", rid=rid, n=missed)

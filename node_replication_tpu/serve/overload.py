@@ -62,7 +62,7 @@ from node_replication_tpu.analysis.locks import make_lock
 from typing import Callable
 
 from node_replication_tpu.obs.metrics import get_registry
-from node_replication_tpu.utils.trace import get_tracer
+from node_replication_tpu.obs.recorder import get_tracer
 
 #: priority classes for `ServeFrontend.submit(op, priority=...)`.
 #: Lower value = more important; shedding order is strictly reversed

@@ -74,7 +74,7 @@ from node_replication_tpu.serve.errors import (
 )
 from node_replication_tpu.shard.ring import ShardMap, ShardMapCorruptError
 from node_replication_tpu.utils.clock import get_clock
-from node_replication_tpu.utils.trace import get_tracer
+from node_replication_tpu.obs.recorder import get_tracer
 
 # ==========================================================================
 # error encoding (typed errors survive the wire)

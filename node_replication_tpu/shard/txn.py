@@ -74,7 +74,7 @@ from node_replication_tpu.serve.errors import (
     WrongShard,
 )
 from node_replication_tpu.utils.clock import get_clock
-from node_replication_tpu.utils.trace import get_tracer
+from node_replication_tpu.obs.recorder import get_tracer
 
 
 def _op_matches(stored: tuple, wanted: tuple) -> bool:

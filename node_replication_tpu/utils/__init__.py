@@ -1,4 +1,4 @@
-"""Utilities: tracing/observability helpers + the injectable clock."""
+"""Utilities: the injectable clock (tracing lives in `obs/`)."""
 
 from node_replication_tpu.utils.clock import (
     Clock,
@@ -7,15 +7,11 @@ from node_replication_tpu.utils.clock import (
     get_clock,
     set_clock,
 )
-from node_replication_tpu.utils.trace import Tracer, get_tracer, span
 
 __all__ = [
     "Clock",
     "RealClock",
     "SimClock",
-    "Tracer",
     "get_clock",
-    "get_tracer",
     "set_clock",
-    "span",
 ]

@@ -133,7 +133,7 @@ class TestObsInTraced:
     def test_tracer_emit_in_jit_fires(self, tmp_path):
         diags = lint_src(tmp_path, """
             import jax
-            from node_replication_tpu.utils.trace import get_tracer
+            from node_replication_tpu.obs.recorder import get_tracer
 
             @jax.jit
             def f(x):
@@ -155,7 +155,7 @@ class TestObsInTraced:
 
     def test_host_loop_instrumentation_clean(self, tmp_path):
         diags = lint_src(tmp_path, """
-            from node_replication_tpu.utils.trace import get_tracer
+            from node_replication_tpu.obs.recorder import get_tracer
 
             def exec_round(x):
                 get_tracer().emit("exec-round")

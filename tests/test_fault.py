@@ -776,7 +776,7 @@ class TestFaultReportSection:
         # end to end: a real quarantine+repair, traced in memory mode,
         # renders a fault section
         from node_replication_tpu.obs.report import analyze
-        from node_replication_tpu.utils.trace import get_tracer
+        from node_replication_tpu.obs.recorder import get_tracer
 
         tracer = get_tracer()
         was = tracer.enabled

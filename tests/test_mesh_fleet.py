@@ -416,7 +416,7 @@ class TestMeshFused:
         # live (R, capacity, devices) point — the fused-calibration
         # event carries devices=, and growth recalibrates
         monkeypatch.setenv("NR_TPU_FUSED_CAL", "1")
-        from node_replication_tpu.utils.trace import get_tracer
+        from node_replication_tpu.obs.recorder import get_tracer
 
         mesh = replica_mesh(4)
         t = get_tracer()
@@ -496,7 +496,7 @@ class TestMeshFused:
         # events must carry the mesh_fused tier and kernel-launch
         # events the mesh width, with responses exact
         from node_replication_tpu.serve import ServeConfig, ServeFrontend
-        from node_replication_tpu.utils.trace import get_tracer
+        from node_replication_tpu.obs.recorder import get_tracer
 
         mesh = replica_mesh(2)
         nr = NodeReplicated(make_seqreg(8), n_replicas=2,

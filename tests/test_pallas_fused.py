@@ -401,7 +401,7 @@ class TestAutoWinnerSelection:
         # recalibrates (second fused-calibration event, fenced key),
         # and unfencing restores the original measured verdict
         monkeypatch.setenv("NR_TPU_FUSED_CAL", "1")
-        from node_replication_tpu.utils.trace import get_tracer
+        from node_replication_tpu.obs.recorder import get_tracer
 
         t = get_tracer()
         t.enable(None)
@@ -513,7 +513,7 @@ class TestCNRFused:
 class TestServeFused:
     def test_serve_roundtrip_and_event_tier(self):
         from node_replication_tpu.serve import ServeConfig, ServeFrontend
-        from node_replication_tpu.utils.trace import get_tracer
+        from node_replication_tpu.obs.recorder import get_tracer
 
         nr = NodeReplicated(make_hashmap(31), n_replicas=2,
                             log_entries=512, gc_slack=64,

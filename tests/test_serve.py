@@ -700,7 +700,7 @@ class TestServeReportSection:
 class TestServeMetricsAndTrace:
     def test_counters_and_trace_events(self):
         from node_replication_tpu.obs.metrics import get_registry
-        from node_replication_tpu.utils.trace import get_tracer
+        from node_replication_tpu.obs.recorder import get_tracer
 
         reg = get_registry()
         was = reg.enabled
@@ -1053,7 +1053,7 @@ class TestPipelinedServing:
 
     def test_serve_assemble_event_and_report_line(self):
         from node_replication_tpu.obs.report import analyze, render
-        from node_replication_tpu.utils.trace import get_tracer
+        from node_replication_tpu.obs.recorder import get_tracer
 
         tracer = get_tracer()
         was = tracer.enabled

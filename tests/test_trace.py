@@ -10,7 +10,7 @@ from node_replication_tpu.core.log import WARN_ROUNDS
 from node_replication_tpu.core.cnr import MultiLogReplicated
 from node_replication_tpu.core.replica import NodeReplicated
 from node_replication_tpu.models import HM_GET, HM_PUT, make_hashmap
-from node_replication_tpu.utils.trace import get_tracer
+from node_replication_tpu.obs.recorder import get_tracer
 
 
 def _with_mem_tracer(fn):
