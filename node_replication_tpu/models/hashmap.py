@@ -90,6 +90,13 @@ def make_hashmap(n_keys: int, prefill_value: int | None = None) -> Dispatch:
         (fused step AND union-window catch-up — the plan is
         prefix-absorbing: per-key finals are absolute); the vmapped
         merge is the honest per-replica dense blend.
+
+        Cost: an O(W log W) sort, O(W) gathers and scatters, and O(K)
+        fills of the dense outputs — no gather indexed by all K slots.
+        Each key's last writer is the final entry of its sorted group,
+        so it is unique: the scatters of those entries never collide and
+        are deterministic. Slots the window does not touch read
+        `touched=False` and zeros, which the merge never reads.
         """
         W = opcodes.shape[0]
         k = args[:, 0] % n_keys
@@ -100,7 +107,6 @@ def make_hashmap(n_keys: int, prefill_value: int | None = None) -> Dispatch:
         # inactive slots (NOOP / unknown opcodes) group into a sentinel
         # bucket past the keyspace so they never touch real keys
         key_eff = jnp.where(active, k, n_keys).astype(jnp.int64)
-        idx = jnp.arange(W, dtype=jnp.int64)
         # stable key grouping: argsort is stable, so equal keys keep
         # window order — no composite `key*(W+1)+idx` key, which would
         # overflow int32 under the NR_TPU_NO_X64=1 opt-out (ADVICE r3)
@@ -122,19 +128,21 @@ def make_hashmap(n_keys: int, prefill_value: int | None = None) -> Dispatch:
             is_rem[order], pres_before.astype(jnp.int32), jnp.int32(0)
         )
         resps = jnp.zeros((W,), jnp.int32).at[order].set(resp_sorted)
-        # last active entry per key wins (scatter-max of window position;
-        # sentinel bucket absorbs inactive slots)
-        last = (
-            jnp.full((n_keys + 1,), -1, jnp.int64)
-            .at[key_eff].max(idx)[:n_keys]
+        # last active entry per key wins: the final entry of each sorted
+        # group; every other entry (and the sentinel bucket) scatters to
+        # index n_keys, which mode="drop" discards
+        is_last = jnp.concatenate(
+            [sk[1:] != sk[:-1], jnp.ones((1,), jnp.bool_)]
         )
-        touched = last >= 0
-        li = jnp.clip(last, 0).astype(jnp.int32)
-        last_is_put = is_put[li]
+        dst = jnp.where(is_last & (sk < n_keys), sk, n_keys)
+        put_sorted = is_put[order]
         return {
-            "touched": touched,
-            "value": jnp.where(last_is_put, v[li], 0),
-            "present": last_is_put,
+            "touched": jnp.zeros((n_keys,), jnp.bool_)
+            .at[dst].set(True, mode="drop"),
+            "value": jnp.zeros((n_keys,), v.dtype)
+            .at[dst].set(jnp.where(put_sorted, v[order], 0), mode="drop"),
+            "present": jnp.zeros((n_keys,), jnp.bool_)
+            .at[dst].set(put_sorted, mode="drop"),
             "resps": resps,
         }
 

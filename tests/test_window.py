@@ -98,6 +98,157 @@ class TestWindowApplySingle:
         assert not np.any(np.asarray(resps))
 
 
+def gather_plan_reference(n_keys):
+    """The hashmap plan as it was built by gathering for every slot: a
+    scatter-max of window positions into an int64[K+1] table, then two
+    K-index gathers. Kept as a reference for the scatter-built plan."""
+
+    def plan(state, opcodes, args):
+        W = opcodes.shape[0]
+        k = args[:, 0] % n_keys
+        v = args[:, 1]
+        is_put = opcodes == HM_PUT
+        is_rem = opcodes == HM_REMOVE
+        key_eff = jnp.where(is_put | is_rem, k, n_keys).astype(jnp.int64)
+        idx = jnp.arange(W, dtype=jnp.int64)
+        order = jnp.argsort(key_eff, stable=True)
+        sk = key_eff[order]
+        same_prev = jnp.concatenate(
+            [jnp.zeros((1,), jnp.bool_), sk[1:] == sk[:-1]]
+        )
+        prev = jnp.concatenate([order[:1], order[:-1]])
+        init_present = state["present"].at[
+            sk.astype(jnp.int32)
+        ].get(mode="clip")
+        pres_before = jnp.where(same_prev, is_put[prev], init_present)
+        resp_sorted = jnp.where(
+            is_rem[order], pres_before.astype(jnp.int32), jnp.int32(0)
+        )
+        resps = jnp.zeros((W,), jnp.int32).at[order].set(resp_sorted)
+        last = (
+            jnp.full((n_keys + 1,), -1, jnp.int64)
+            .at[key_eff].max(idx)[:n_keys]
+        )
+        li = jnp.clip(last, 0).astype(jnp.int32)
+        last_is_put = is_put[li]
+        return {
+            "touched": last >= 0,
+            "value": jnp.where(last_is_put, v[li], 0),
+            "present": last_is_put,
+            "resps": resps,
+        }
+
+    return plan
+
+
+def sparse_window(rng, K, W):
+    """A window over a keyspace far larger than itself: keys drawn from a
+    small pool (duplicates, REMOVEs as keys' last writers), some at
+    or past K (they wrap by the modulus), NOOPs and unknown opcode 7."""
+    pool = rng.integers(0, 3 * K, max(W // 4, 1))
+    opcodes = rng.choice([0, HM_PUT, HM_REMOVE, 7], size=W,
+                         p=[0.15, 0.45, 0.3, 0.1])
+    keys = rng.choice(pool, size=W)
+    vals = rng.integers(1, 1 << 30, W)
+    # the last writer of key 3 (present at start) is a REMOVE
+    opcodes[-2:] = [HM_PUT, HM_REMOVE]
+    keys[-2:] = K + 3
+    return (jnp.asarray(opcodes, jnp.int32),
+            jnp.asarray(np.stack([keys, vals, np.zeros(W)], axis=1),
+                        jnp.int32))
+
+
+def assert_plans_agree(got, ref):
+    touched = np.asarray(ref["touched"])
+    np.testing.assert_array_equal(np.asarray(got["touched"]), touched)
+    np.testing.assert_array_equal(np.asarray(got["resps"]),
+                                  np.asarray(ref["resps"]))
+    for name in ("value", "present"):
+        np.testing.assert_array_equal(np.asarray(got[name])[touched],
+                                      np.asarray(ref[name])[touched])
+
+
+def assert_states_equal(got, ref):
+    for name in ("values", "present"):
+        np.testing.assert_array_equal(np.asarray(got[name]),
+                                      np.asarray(ref[name]))
+
+
+class TestHashmapPlanSparse:
+    """The plan at K >> W, where it must scatter the window's last
+    writes rather than gather for every slot, against the sequential fold
+    and the gather-built plan."""
+
+    K = 1 << 16
+
+    def _state0(self, d):
+        state0 = d.init_state()
+        state0["present"] = state0["present"].at[::3].set(True)
+        state0["values"] = state0["values"].at[::3].set(5)
+        return state0
+
+    def _check(self, d, state0, opcodes, args):
+        ref_state, ref_resps = fold_jit(d, state0, opcodes, args)
+        old_plan = gather_plan_reference(self.K)(state0, opcodes, args)
+        plan = d.window_plan(state0, opcodes, args)
+        assert_plans_agree(plan, old_plan)
+        for got_state, got_resps in (
+            d.window_apply(state0, opcodes, args),
+            d.window_merge(state0, plan),
+            d.window_merge(state0, old_plan),
+        ):
+            assert_states_equal(got_state, ref_state)
+            assert [int(x) for x in got_resps] == ref_resps
+
+    @pytest.mark.parametrize("W", [64, 256])
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_matches_fold_and_gather_plan(self, seed, W):
+        d = make_hashmap(self.K)
+        opcodes, args = sparse_window(np.random.default_rng(seed), self.K, W)
+        self._check(d, self._state0(d), opcodes, args)
+
+    @pytest.mark.parametrize("W", [64, 256])
+    def test_all_noop_window(self, W):
+        d = make_hashmap(self.K)
+        opcodes = jnp.asarray(np.tile([0, 7], W // 2), jnp.int32)
+        args = jnp.ones((W, 3), jnp.int32)
+        self._check(d, self._state0(d), opcodes, args)
+        assert not np.any(np.asarray(
+            d.window_plan(self._state0(d), opcodes, args)["touched"]))
+
+    def test_plan_has_no_slot_sized_gather(self):
+        # structural guard: every gather yields fewer than K elements and
+        # every scatter takes at most W updates; the gather-built plan
+        # shows what the guard catches
+        K, W = self.K, 256
+        d = make_hashmap(K)
+        opcodes, args = sparse_window(np.random.default_rng(0), K, W)
+        state0 = d.init_state()
+
+        def violations(plan_fn):
+            found = []
+            stack = [jax.make_jaxpr(plan_fn)(state0, opcodes, args).jaxpr]
+            while stack:
+                jaxpr = stack.pop()
+                for eqn in jaxpr.eqns:
+                    name = eqn.primitive.name
+                    if (name == "gather"
+                            and eqn.outvars[0].aval.size >= K):
+                        found.append(name)
+                    if (name.startswith("scatter")
+                            and eqn.invars[2].aval.size > W):
+                        found.append(name)
+                    for p in eqn.params.values():
+                        for sub in p if isinstance(p, tuple) else (p,):
+                            sub = getattr(sub, "jaxpr", sub)
+                            if hasattr(sub, "eqns"):
+                                stack.append(sub)
+            return found
+
+        assert violations(d.window_plan) == []
+        assert violations(gather_plan_reference(K)) == ["gather", "gather"]
+
+
 class TestSortedSetWindowApply:
     @pytest.mark.parametrize("seed", [0, 1])
     def test_matches_sequential_fold(self, seed):
