@@ -580,16 +580,25 @@ def _catchup_union_plan(
     donor = jnp.argmin(masked_lt)
     donor_state = jax.tree.map(lambda x: x[donor], states)
     plan = d.window_plan(donor_state, opcodes, args)
-    merged, presps = jax.vmap(lambda s: d.window_merge(s, plan))(states)
-    take = log.ltails < end
-    if fenced is not None:
-        take = take & ~fenced
-    states = jax.tree.map(
-        lambda a, b: jnp.where(
-            take.reshape((-1,) + (1,) * (a.ndim - 1)), b, a
-        ),
-        states, merged,
-    )
+
+    def replicas_taking():
+        take = log.ltails < end
+        return take if fenced is None else take & ~fenced
+
+    if d.window_merge_stacked is not None:
+        # the model writes the plan into the taking replicas itself,
+        # with no pass over the rest of the state
+        states, presps = d.window_merge_stacked(states, plan,
+                                                replicas_taking())
+    else:
+        merged, presps = jax.vmap(lambda s: d.window_merge(s, plan))(states)
+        take = replicas_taking()
+        states = jax.tree.map(
+            lambda a, b: jnp.where(
+                take.reshape((-1,) + (1,) * (a.ndim - 1)), b, a
+            ),
+            states, merged,
+        )
     if need_resps:
         # response layout contract: resps[r, i] answers logical position
         # old_ltails[r] + i — gathered from the canonical per-position

@@ -319,14 +319,21 @@ def _named(fn, name: str):
     return fn
 
 
-def replicate_state(state, n_replicas: int):
-    """Stack one replica state into an [R, ...] lock-step fleet."""
+@partial(jax.jit, static_argnums=1)
+def _replicated(state, n_replicas: int):
     return jax.tree.map(
-        lambda x: jnp.broadcast_to(
-            x[None], (n_replicas,) + x.shape
-        ).copy(),
+        lambda x: jnp.broadcast_to(x[None], (n_replicas,) + x.shape),
         state,
     )
+
+
+def replicate_state(state, n_replicas: int):
+    """Stack one replica state into an [R, ...] lock-step fleet. One
+    program, so the fleet is the only buffer it allocates: eager ops
+    would also materialize the expanded state and a broadcast copy,
+    twice the fleet at the peak (a record store of 2.1 GB a replica
+    would not fit 4 replicas in 16 GB)."""
+    return _replicated(state, int(n_replicas))
 
 
 def states_equal(states) -> bool:
@@ -977,6 +984,9 @@ class NodeReplicated(_FusedTier):
             return apply_read(dispatch, state, opcode, args)
 
         self._read_jit = jax.jit(_read_one)
+        if dispatch.read_stacked is not None:
+            self._read_stacked_jit = jax.jit(
+                _named(partial(dispatch.read_stacked), "read_stacked"))
 
     # ------------------------------------------------------------------ API
 
@@ -1308,7 +1318,9 @@ class NodeReplicated(_FusedTier):
         """Read path (`Replica::execute` → `read_only`,
         `nr/src/replica.rs:404-410`, `483-497`): wait until this replica has
         replayed up to the completed tail (helping replay while waiting),
-        then dispatch locally against replica state."""
+        then dispatch locally against replica state. Answers an int, or
+        an `np.ndarray` of int32 words where the model gives a wide read
+        (`Dispatch.read_stacked`)."""
         rid = token.rid
         if self._is_fenced(rid):
             raise ReplicaFencedError(rid)
@@ -1320,15 +1332,36 @@ class NodeReplicated(_FusedTier):
             rounds = self._watchdog(rounds, "read-sync")
         return self._dispatch_read(rid, op)
 
-    def _dispatch_read(self, rid: int, op: tuple) -> int:
+    def _dispatch_read(self, rid: int, op: tuple) -> int | np.ndarray:
         """Shared read-dispatch tail: pack args, run the read jit
         against replica `rid`'s current state. `execute` (synced) and
         `execute_stale` (brownout) must never diverge on this step.
         Caller holds the combiner lock and has fence-checked."""
+        if self.dispatch.read_stacked is not None:
+            return self._dispatch_read_stacked(rid, op)
         args = np.zeros((self.spec.arg_width,), np.int32)
         args[: len(op) - 1] = op[1:]
         return int(
             self._read_jit(
+                self.states,
+                jnp.int32(rid),
+                jnp.int32(op[0]),
+                jnp.asarray(args),
+            )
+        )
+
+    def _dispatch_read_stacked(self, rid: int, op: tuple) -> np.ndarray:
+        """`_dispatch_read` for a model with a wide read
+        (`Dispatch.read_stacked`): one program gathers the answer from
+        the stacked states, and its words come back to the host. Caller
+        holds the combiner lock."""
+        d = self.dispatch
+        if d.host_counters is not None and get_registry().enabled:
+            d.host_counters("read", [op])
+        args = np.zeros((self.spec.arg_width,), np.int32)
+        args[: len(op) - 1] = op[1:]
+        return np.asarray(
+            self._read_stacked_jit(
                 self.states,
                 jnp.int32(rid),
                 jnp.int32(op[0]),
@@ -1543,6 +1576,9 @@ class NodeReplicated(_FusedTier):
         opcodes, args, _ = encode_ops(
             ops, self.spec.arg_width, pad_to=pad
         )
+        counters = self.dispatch.host_counters
+        if counters is not None and get_registry().enabled:
+            counters("append", ops)
         fkey = self._fused_fence_key()  # once per round: O(R) scan
         timing = self._fused_calibrating(fkey)
         defer = defer and not timing

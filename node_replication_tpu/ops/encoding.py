@@ -10,7 +10,9 @@ structure is described by a `Dispatch` value holding
   `D: Default`, `nr/examples/stack.rs:30-35`; deterministic init is the
   recovery model, SURVEY.md §5),
 - `write_ops[i]`  : (state, args) -> (state, resp)   — pure `dispatch_mut`,
-- `read_ops[i]`   : (state, args) -> resp            — pure `dispatch`.
+- `read_ops[i]`   : (state, args) -> resp            — pure `dispatch`; resp
+  is one int32 word, or a vector of int32 words where the Dispatch gives
+  a `read_stacked` (then answered through it).
 
 Opcode 0 is reserved as NOOP in both spaces so that padded / masked batch
 slots replay as no-ops (the fixed-shape substitute for the reference's
@@ -36,8 +38,11 @@ PyTree = Any
 # Reserved opcode: replay/padding no-op in both the write and read spaces.
 NOOP = 0
 
-# Responses are a single int32 lane. The reference's responses are
-# word-sized as well (`Response = Option<u64>` style, e.g.
+# Responses are int32 words. A write answers one word, and so does a read
+# unless its Dispatch gives a `read_stacked`, whose answer is as wide as
+# the vector it returns (the record store's read answers a whole record
+# of 250 words, `models/records.py`). The reference's
+# responses are word-sized as well (`Response = Option<u64>` style, e.g.
 # `nr/examples/stack.rs:46-49`); "None" is conventionally encoded as -1 by
 # the bundled models.
 RESP_DTYPE = jnp.int32
@@ -121,6 +126,14 @@ class Dispatch:
     # class docstring). Mere presence of window_plan/window_merge only
     # claims the weaker lock-step contract.
     window_canonical: bool = False
+    # Optional, with a canonical plan: `window_merge_stacked(states, plan,
+    # take) -> (states, resps)` merges the plan into the replicas of the
+    # stacked [R, ...] states that `take` (bool[R]) marks, and leaves the
+    # others as they are. The union-window tier then runs it in place of
+    # a vmapped `window_merge` and a select over every replica's whole
+    # state: a model whose plan touches a few rows of a large state
+    # (`models/records.py`) writes those rows and nothing else.
+    window_merge_stacked: Callable | None = None
     # Fused pallas combiner-round engine (optional): a callable
     # `(spec: LogSpec, interpret=None) -> engine` building the model's
     # one-kernel-launch append+replay round (e.g.
@@ -132,6 +145,18 @@ class Dispatch:
     # back to the append+exec chain (`core/replica.py` winner
     # selection).
     fused_factory: Callable | None = None
+    # A read answers one int32 (through `apply_read` on the replica's
+    # state) for every model but one with a wide read, which gives
+    # `read_stacked(states, rid, opcode, args) -> int32[n]`: it indexes
+    # the stacked [R, ...] states itself so that a read never slices a
+    # whole replica out. `NodeReplicated.execute` then runs it as its own
+    # program (`jit_read_stacked`) and answers an `np.ndarray` of n words.
+    read_stacked: Callable | None = None
+    # Host-side instrumentation (optional): `host_counters(event, ops)`
+    # with event "append" (a combiner round's batch, as it is encoded) or
+    # "read" (one read op). Called only while the obs registry is
+    # enabled, never inside a traced function.
+    host_counters: Callable | None = None
 
     @property
     def n_write_ops(self) -> int:

@@ -1380,7 +1380,10 @@ class ServeFrontend:
         """Read against replica `rid` via the wrapper's read-sync path
         (`execute`): waits only for THIS replica to pass the completed
         tail, then dispatches locally — never enters the write queue
-        or the log (`nr/src/replica.rs:404-410`).
+        or the log (`nr/src/replica.rs:404-410`). Answers what
+        `execute` answers: an int, or an `np.ndarray` of int32 words for
+        a model with a wide read (`Dispatch.read_stacked`, the record
+        store's 250-word record).
 
         `min_pos` is the bounded-staleness cursor (the `repl/`
         follower read path): the read dispatches only once replica

@@ -213,8 +213,50 @@ class SeqRegOracle(Oracle):
         return o
 
 
+class RecordsOracle(Oracle):
+    """`models/records.py`: rows of 256 words, field f of record k in
+    words [25 f, 25 f + 25) of row `k % K` (the last 6 words stay 0);
+    REC_UPDATE sets one field and presence, REC_READ answers the 250
+    words of the record or -1 in each while it is absent."""
+
+    model = "records"
+    FIELDS, FIELD_WORDS, ROW_WORDS = 10, 25, 256
+
+    def __init__(self, n_records: int):
+        self.n = int(n_records)
+        self.fields = np.zeros((self.n, self.ROW_WORDS), np.int32)
+        self.present = np.zeros(self.n, np.bool_)
+
+    def apply(self, op):
+        code, k = int(op[0]), int(op[1]) % self.n
+        if code != 1:  # REC_UPDATE
+            raise ValueError(f"unknown records write opcode {code}")
+        c = (int(op[2]) % self.FIELDS) * self.FIELD_WORDS
+        self.fields[k, c:c + self.FIELD_WORDS] = np.asarray(
+            op[3:3 + self.FIELD_WORDS], np.int32)
+        self.present[k] = True
+        return 0
+
+    def read(self, op):
+        k = int(op[1]) % self.n  # REC_READ
+        words = self.FIELDS * self.FIELD_WORDS
+        if not self.present[k]:
+            return np.full(words, -1, np.int32)
+        return self.fields[k, :words].copy()
+
+    def arrays(self):
+        return {"fields": self.fields, "present": self.present}
+
+    def copy(self):
+        o = RecordsOracle(self.n)
+        o.fields = self.fields.copy()
+        o.present = self.present.copy()
+        return o
+
+
 _FACTORIES = {
     "hashmap": HashmapOracle,
+    "records": RecordsOracle,
     "stack": StackOracle,
     "queue": QueueOracle,
     "seqreg": SeqRegOracle,
