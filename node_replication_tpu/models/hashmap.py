@@ -11,7 +11,8 @@ sparse keyspaces lives in `models/oahashmap.py`.
 Write opcodes: HM_PUT=1 (args k, v → resp 0), HM_REMOVE=2 (args k → resp 1
 if the key was present else 0).
 Read opcodes: HM_GET=1 (args k → resp value, or -1 when absent — the
-encoding of the reference's `Option<u64>` response).
+encoding of the reference's `Option<u64>` response). `NodeReplicated`
+answers a get through `read_stacked`, one slot of the stacked replicas.
 Keys hash onto the dense table with `k % K` (uniform bench keys are already
 dense; the modulus mirrors a hash).
 """
@@ -67,6 +68,16 @@ def make_hashmap(n_keys: int, prefill_value: int | None = None) -> Dispatch:
         return jnp.where(
             state["present"][k], state["values"][k], jnp.int32(ABSENT)
         )
+
+    def read_stacked(states, rid, opcode, args):
+        """`get` of replica `rid`, gathered from the stacked [R, K]
+        planes: one slot, where slicing the replica out first would copy
+        its whole row. Any other opcode answers 0 (the NOOP read's
+        answer)."""
+        k = args[0] % n_keys
+        got = jnp.where(states["present"][rid, k], states["values"][rid, k],
+                        jnp.int32(ABSENT))
+        return jnp.where(opcode == HM_GET, got, jnp.int32(0))
 
     def window_plan(state, opcodes, args):
         """Combined replay of a whole window (see `Dispatch.window_apply`).
@@ -183,4 +194,5 @@ def make_hashmap(n_keys: int, prefill_value: int | None = None) -> Dispatch:
         # tests/test_window.py::test_plan_is_prefix_absorbing
         window_canonical=True,
         fused_factory=fused_factory,
+        read_stacked=read_stacked,
     )

@@ -11,8 +11,9 @@ structure is described by a `Dispatch` value holding
   recovery model, SURVEY.md §5),
 - `write_ops[i]`  : (state, args) -> (state, resp)   — pure `dispatch_mut`,
 - `read_ops[i]`   : (state, args) -> resp            — pure `dispatch`; resp
-  is one int32 word, or a vector of int32 words where the Dispatch gives
-  a `read_stacked` (then answered through it).
+  is one int32 word, or a vector of int32 words where the Dispatch's
+  `read_stacked` returns one (reads are answered through `read_stacked`
+  wherever the Dispatch gives it).
 
 Opcode 0 is reserved as NOOP in both spaces so that padded / masked batch
 slots replay as no-ops (the fixed-shape substitute for the reference's
@@ -39,9 +40,9 @@ PyTree = Any
 NOOP = 0
 
 # Responses are int32 words. A write answers one word, and so does a read
-# unless its Dispatch gives a `read_stacked`, whose answer is as wide as
-# the vector it returns (the record store's read answers a whole record
-# of 250 words, `models/records.py`). The reference's
+# unless its Dispatch's `read_stacked` returns a vector, which is then the
+# answer (the record store's read answers a whole record of 250 words,
+# `models/records.py`). The reference's
 # responses are word-sized as well (`Response = Option<u64>` style, e.g.
 # `nr/examples/stack.rs:46-49`); "None" is conventionally encoded as -1 by
 # the bundled models.
@@ -145,12 +146,14 @@ class Dispatch:
     # back to the append+exec chain (`core/replica.py` winner
     # selection).
     fused_factory: Callable | None = None
-    # A read answers one int32 (through `apply_read` on the replica's
-    # state) for every model but one with a wide read, which gives
-    # `read_stacked(states, rid, opcode, args) -> int32[n]`: it indexes
-    # the stacked [R, ...] states itself so that a read never slices a
-    # whole replica out. `NodeReplicated.execute` then runs it as its own
-    # program (`jit_read_stacked`) and answers an `np.ndarray` of n words.
+    # Optional: `read_stacked(states, rid, opcode, args) -> int32[] or
+    # int32[n]`, the read op of replica `rid` indexed in the stacked
+    # [R, ...] states, so that a read never slices a whole replica out.
+    # It answers as `apply_read` would, 0 for NOOP and unknown opcodes.
+    # `NodeReplicated.execute` runs it as its own program
+    # (`jit_read_stacked`) and answers an int for a 0-d answer (the
+    # hashmap's get), else an `np.ndarray` of n words (the record
+    # store's read).
     read_stacked: Callable | None = None
     # Host-side instrumentation (optional): `host_counters(event, ops)`
     # with event "append" (a combiner round's batch, as it is encoded) or

@@ -1381,9 +1381,10 @@ class ServeFrontend:
         (`execute`): waits only for THIS replica to pass the completed
         tail, then dispatches locally — never enters the write queue
         or the log (`nr/src/replica.rs:404-410`). Answers what
-        `execute` answers: an int, or an `np.ndarray` of int32 words for
-        a model with a wide read (`Dispatch.read_stacked`, the record
-        store's 250-word record).
+        `execute` answers: an int for a one-word read (the hashmap's
+        get, gathered through `Dispatch.read_stacked`), or an
+        `np.ndarray` of int32 words for a wide read (the record store's
+        250-word record).
 
         `min_pos` is the bounded-staleness cursor (the `repl/`
         follower read path): the read dispatches only once replica

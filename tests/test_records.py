@@ -26,8 +26,11 @@ from node_replication_tpu.models import (
     HM_PUT,
     REC_READ,
     REC_UPDATE,
+    ST_PEEK,
+    ST_PUSH,
     make_hashmap,
     make_records,
+    make_stack,
 )
 from node_replication_tpu.models.records import (
     ABSENT,
@@ -169,13 +172,21 @@ class TestWideRead:
         assert not got[:100].any() and not got[125:].any()
         assert (nr.execute((REC_READ, 7), t1) == ABSENT).all()
 
-    def test_scalar_read_still_answers_an_int(self):
-        nr = NodeReplicated(make_hashmap(16), n_replicas=2)
+    @pytest.mark.parametrize("model", ["hashmap", "stack"])
+    def test_scalar_read_still_answers_an_int(self, model):
+        """A one-word read answers an int: the hashmap's get through its
+        `read_stacked`, the stack's peek through the sliced replica
+        (`_read_one`), the form of a model without `read_stacked`."""
+        d, write, read = {
+            "hashmap": (make_hashmap(16), (HM_PUT, 3, 42), (HM_GET, 3)),
+            "stack": (make_stack(16), (ST_PUSH, 42), (ST_PEEK,)),
+        }[model]
+        nr = NodeReplicated(d, n_replicas=2)
         t0, t1 = nr.register(0), nr.register(1)
-        nr.execute_mut((HM_PUT, 3, 42), t0)
-        got = nr.execute((HM_GET, 3), t1)
+        nr.execute_mut(write, t0)
+        got = nr.execute(read, t1)
         assert type(got) is int and got == 42
-        assert not hasattr(nr, "_read_stacked_jit")
+        assert hasattr(nr, "_read_stacked_jit") == (model == "hashmap")
 
     def test_read_stacked_alone_selects_the_wide_read(self):
         """No width is declared: a Dispatch with `read_stacked` answers
@@ -324,6 +335,20 @@ class TestCompileAtSize:
         m = c.memory_analysis()
         assert m.temp_size_in_bytes < 2**20
         assert m.output_size_in_bytes == RECORD_WORDS * 4
+
+
+def test_hashmap_get_gathers_one_slot_at_size():
+    """The served hashmap's size, 2^26 slots x 4 replicas, lowered from
+    shapes: the get gathers one slot of the stacked planes, with no
+    temporary of a replica's row (5 B a slot, 320 MB)."""
+    d = make_hashmap(1 << 26)
+    st_s = jax.eval_shape(lambda: replicate_state(d.init_state(), R))
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+    c = jax.jit(d.read_stacked).lower(
+        st_s, i32, i32, jax.ShapeDtypeStruct((d.arg_width,), jnp.int32)).compile()
+    m = c.memory_analysis()
+    assert m.temp_size_in_bytes < 2**20
+    assert m.output_size_in_bytes == 4
 
 
 def test_union_catchup_matches_the_scan_with_divergent_cursors():
