@@ -187,10 +187,9 @@ class MultiLogReplicated(_FusedTier):
         # ---- fused pallas per-log combiner tier (the NodeReplicated
         # twin, `core/replica._FusedTier`): a per-log sub-batch whose
         # log is lock-step eligible appends+replays+answers as ONE
-        # kernel launch. engine='pallas' forces it, 'auto' calibrates
-        # on TPU (NR_TPU_FUSED_CAL=1 is the CPU-test hook), 'scan'
-        # keeps the chain. CNR has no fencing, so the fenced kernel
-        # variant never builds here.
+        # kernel launch. Only engine='pallas' runs it; 'auto' and
+        # 'scan' keep the chain and never consult it. CNR has no
+        # fencing, so the fenced kernel variant never builds here.
         if engine not in ("auto", "scan", "pallas"):
             raise ValueError(f"unknown engine {engine!r}")
         self._fused_cnr_cache: dict = {}
@@ -443,7 +442,7 @@ class MultiLogReplicated(_FusedTier):
         carry). With `pending` (the split-round path) the kernel is
         launched here and the response readback deferred to
         `_finish_round_log`."""
-        eng = self._fused_tier_wanted(pad)
+        eng = self._fused_engine()
         if eng is None:
             return False
         if not eng.supports(pad):
@@ -458,7 +457,6 @@ class MultiLogReplicated(_FusedTier):
         if not (int(lts.min()) == tail == int(lts.max())):
             self._m_fused_fallback.inc()
             return False
-        timing = self._fused_calibrating()
         t0 = time.perf_counter()
         fn = self._fused_cnr_round(eng, pad)
         extra = {"deferred": True} if pending is not None else {}
@@ -472,8 +470,6 @@ class MultiLogReplicated(_FusedTier):
                 resps_np = np.asarray(resps)
                 sp.fence(self.ml, self.states)
         dt = time.perf_counter() - t0
-        if timing:
-            self._note_fused_sample("pallas_fused", pad, dt)
         # the CNR path embeds the raw round_fn in its own program, so
         # the engine's round() wrapper never runs — report through the
         # same instrumentation hook (tier counter + kernel.* metrics +
@@ -500,10 +496,8 @@ class MultiLogReplicated(_FusedTier):
         `_begin_round` twin): wait for ring space on this log, encode
         + append, record each op's in-flight response destination.
         `defer=True` leaves the replay-to-target (or the fused
-        launch's readback) for `_finish_round_log`; calibration rounds
-        ignore `defer` (honest tier timing needs the round
-        back-to-back). The lock is reentrant: callers already hold
-        it."""
+        launch's readback) for `_finish_round_log`. The lock is
+        reentrant: callers already hold it."""
         fault_hook("append", rid, self)
         n = len(ops)
         self._combine_rounds[log_idx] += 1
@@ -521,19 +515,14 @@ class MultiLogReplicated(_FusedTier):
         opcodes, args, _ = encode_ops(
             ops, self.spec.arg_width, pad_to=pad
         )
-        timing = self._fused_calibrating()
-        defer = defer and not timing
         pending = _PendingRound(rid, list(tids), n, pos0, batch=batch,
                                 log_idx=log_idx)
-        pending.pad = pad
         if self._try_fused_round_log(log_idx, rid, ops, tids, n, pos0,
                                      pad, opcodes, args,
                                      pending if defer else None):
             if pending.fused_resps is None:
                 pending.done = True  # ran eagerly end-to-end
             return pending
-        if timing:
-            pending.t_chain = time.perf_counter()
         extra = {"batch": True} if batch else {}
         with span("append", log=log_idx, rid=rid, n=n, pos0=pos0,
                   **extra) as sp:
@@ -575,10 +564,6 @@ class MultiLogReplicated(_FusedTier):
         self.last_round_tier = "scan"
         self._tier_by_rid[rid] = "scan"
         self._pos_by_rid[rid] = pending.pos0
-        if pending.t_chain is not None:
-            self._note_fused_sample("chain", pending.pad,
-                                    time.perf_counter()
-                                    - pending.t_chain)
 
     @_locked
     def _append_and_replay_log(self, log_idx: int, rid: int,
@@ -818,7 +803,7 @@ class MultiLogReplicated(_FusedTier):
             "exec_rounds": self._exec_rounds,
             "idle_rounds": self._idle_rounds,
             "fused_rounds": self._fused_rounds,
-            "fused_tier": self._fused_tier_state(),
+            "fused_tier": self._fused_mode,
         }
 
     @_locked
@@ -865,7 +850,7 @@ class MultiLogReplicated(_FusedTier):
                 "rounds": self._exec_rounds,
                 "idle_rounds": self._idle_rounds,
                 "fused_rounds": self._fused_rounds,
-                "fused_tier": self._fused_tier_state(),
+                "fused_tier": self._fused_mode,
             },
             "mesh": (
                 None if self.mesh is None else {

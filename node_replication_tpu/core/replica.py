@@ -36,8 +36,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
-import os
-import statistics
 import threading
 
 from node_replication_tpu.analysis.locks import make_rlock
@@ -79,15 +77,6 @@ MAX_THREADS_PER_REPLICA = 256
 
 # Default static replay window per device round (jit-compiled once).
 DEFAULT_EXEC_WINDOW = 256
-
-# Fused-tier winner selection (`engine='auto'` + a fused-capable
-# dispatch): per tier, the first WARMUP eligible rounds absorb compile
-# cost off the books, the next SAMPLES rounds are timed (fenced by the
-# round's own host readback), and the tier with the lower median
-# commits. Both calibration tiers run REAL rounds — results are
-# bit-identical either way, only their speed differs.
-FUSED_CAL_WARMUP = 1
-FUSED_CAL_SAMPLES = 2
 
 # Reserved context key for `execute_mut_batch` response sinks: real
 # thread ids are allocated from 0 upward by `register`, so -1 can never
@@ -145,14 +134,13 @@ class _PendingRound:
     is only this replica's replay-to-target (chain tier) or the
     response readback (fused tier — the kernel is already launched and
     running on the device); `finish` completes it. `done` marks a
-    round that `begin` ran eagerly end-to-end (serial callers,
-    calibration rounds, empty batches) so `finish` only collects.
+    round that `begin` ran eagerly end-to-end (serial callers, empty
+    batches) so `finish` only collects.
     `log_idx` is the CNR per-log variant's mapped log (None for NR).
     """
 
     __slots__ = ("rid", "tids", "n", "pos0", "target", "batch",
-                 "log_idx", "fused_resps", "done", "t_chain", "pad",
-                 "fkey", "tier")
+                 "log_idx", "fused_resps", "done", "tier")
 
     def __init__(self, rid: int, tids: list[int], n: int, pos0: int,
                  batch: bool = False, log_idx: int | None = None):
@@ -166,10 +154,6 @@ class _PendingRound:
         #: device array of the fused launch awaiting readback
         self.fused_resps = None
         self.done = False
-        self.t_chain: float | None = None
-        self.pad = 0
-        #: calibration fence-mask key at begin (chain samples note it)
-        self.fkey: tuple = ()
         #: engine tier of a deferred fused launch (readback delivery)
         self.tier: str | None = None
 
@@ -352,39 +336,20 @@ def states_equal(states) -> bool:
 
 class _FusedTier:
     """Fused-pallas-tier plumbing shared by `NodeReplicated` and
-    `MultiLogReplicated` (`core/cnr.py`): lazy spec-bound engine
-    construction, the calibration sampler, and the winner-selection
-    state machine. Hosts expect the attributes initialized by their
-    constructors (`_fused_mode`, `_fused_choice`, `_fused_verdicts`,
-    `_fused_samples`, `_fused`, `_fused_spec`) and provide
-    `_fused_log_spec()` — the `LogSpec` the engine is built against (a
-    CNR derives one per-log spec for all its logs). All methods run
-    under the host's combiner lock.
+    `MultiLogReplicated` (`core/cnr.py`): the tier is ON only when the
+    caller forces it with `engine='pallas'`, and then builds its
+    spec-bound engine eagerly and rebuilds it lazily after a fleet-shape
+    change. Hosts provide `_fused_log_spec()` — the `LogSpec` the
+    engine is built against (a CNR derives one per-log spec for all its
+    logs). All methods run under the host's combiner lock.
 
     On a mesh (`NodeReplicated(mesh=)`) the tier is the MESH-FUSED
     composition (`parallel/collectives.py:MeshFusedEngine`): the same
     one-launch round wrapped in shard_map with the cursor lattice
-    joined over ICI, competing against the shmap/gspmd chain instead
-    of the single-device one. Calibration is mesh-aware by
-    construction: the verdict is measured at the live (R, capacity,
-    devices) point and reset on `grow_fleet` AND on mesh re-placement
-    (`_place_on_mesh`)."""
+    joined over ICI, in place of the shmap/gspmd chain."""
 
     def _fused_log_spec(self) -> LogSpec:
         return self.spec
-
-    def _fused_fence_key(self) -> tuple:
-        """Calibration key for the CURRENT quarantine mask: the sorted
-        fenced rids (empty when none). Chain and fused timings are
-        only comparable under the same mask — the fenced kernel
-        variant is a DIFFERENT program — so samples and verdicts are
-        keyed on it: a quarantine mid-serve recalibrates instead of
-        routing rounds through a tier whose fenced variant was never
-        timed."""
-        f = getattr(self, "_fenced", None)
-        if f is None:
-            return ()
-        return tuple(int(r) for r in np.where(f)[0])
 
     def _init_fused_tier(self, engine: str, dispatch, mesh, reg,
                          prefix: str, debug: bool = False,
@@ -394,31 +359,12 @@ class _FusedTier:
         FORCES the tier (validated loudly here: the model must carry a
         `fused_factory`, and checkify `debug` has no fused twin; on a
         mesh the host must support the mesh-fused composition —
-        `mesh_fused=True`, NodeReplicated only); `engine='auto'` with a
-        fused-capable dispatch arms the measured calibration on TPU
-        (NR_TPU_FUSED_CAL=1 is the CPU-test hook — in interpret mode
-        the fused tier cannot honestly win); anything else leaves the
-        tier off."""
+        `mesh_fused=True`, NodeReplicated only); any other engine
+        leaves the tier off and never consults it."""
         self._fused = None
         self._fused_spec = None
         self._fused_mode = "off"
-        self._fused_choice: bool | None = False
-        # auto-mode verdicts, keyed by the fence mask (_fused_fence_key)
-        self._fused_verdicts: dict[tuple, bool] = {}
         self._fused_mesh = mesh if mesh_fused else None
-        self._fused_tier_name = (
-            "mesh_fused" if self._fused_mesh is not None
-            else "pallas_fused"
-        )
-        # calibration samples are keyed by (WINDOW, fence mask): chain
-        # and fused timings are only comparable at the same padded
-        # batch size AND the same quarantine mask, and the per-key
-        # warmup absorbs each program's jit compile — a verdict
-        # commits at the first key that fills both sides (see
-        # _note_fused_sample)
-        self._fused_samples: dict[str, dict[tuple, list]] = {
-            "pallas_fused": {}, "chain": {},
-        }
         self._fused_rounds = 0
         self.last_round_tier: str | None = None
         self._tier_by_rid: dict[int, str] = {}
@@ -429,42 +375,32 @@ class _FusedTier:
         self._m_fused_fallback = reg.counter(
             f"{prefix}.exec.engine.fused_fallback"
         )
-        if engine == "pallas":
-            if dispatch.fused_factory is None:
-                raise ValueError(
-                    f"engine='pallas' but {dispatch.name} has no "
-                    f"fused_factory (no fused kernel for this model)"
-                )
-            if mesh is not None and not mesh_fused:
-                raise ValueError(
-                    "engine='pallas' does not take mesh= here (the "
-                    "mesh-fused composition is NodeReplicated-only; "
-                    "the CNR per-log tier runs un-meshed — see README "
-                    "'Engines')"
-                )
-            if debug:
-                raise ValueError(
-                    "engine='pallas' has no checkify twin; use "
-                    "debug=False (the fused round replays inside the "
-                    "kernel, outside the checks' reach)"
-                )
-            # build eagerly so an unsupported config fails loudly at
-            # construction (the explicit ask), not mid-traffic
-            spec = self._fused_log_spec()
-            self._fused = self._build_fused_engine(spec)
-            self._fused_spec = spec
-            self._fused_mode = "forced"
-            self._fused_choice = True
-        elif (
-            engine == "auto"
-            and dispatch.fused_factory is not None
-            and (mesh is None or mesh_fused)
-            and not debug
-            and (jax.default_backend() == "tpu"
-                 or os.environ.get("NR_TPU_FUSED_CAL") == "1")
-        ):
-            self._fused_mode = "auto"
-            self._fused_choice = None  # calibration pending
+        if engine != "pallas":
+            return
+        if dispatch.fused_factory is None:
+            raise ValueError(
+                f"engine='pallas' but {dispatch.name} has no "
+                f"fused_factory (no fused kernel for this model)"
+            )
+        if mesh is not None and not mesh_fused:
+            raise ValueError(
+                "engine='pallas' does not take mesh= here (the "
+                "mesh-fused composition is NodeReplicated-only; "
+                "the CNR per-log tier runs un-meshed — see README "
+                "'Engines')"
+            )
+        if debug:
+            raise ValueError(
+                "engine='pallas' has no checkify twin; use "
+                "debug=False (the fused round replays inside the "
+                "kernel, outside the checks' reach)"
+            )
+        # build eagerly so an unsupported config fails loudly at
+        # construction (the explicit ask), not mid-traffic
+        spec = self._fused_log_spec()
+        self._fused = self._build_fused_engine(spec)
+        self._fused_spec = spec
+        self._fused_mode = "forced"
 
     def _build_fused_engine(self, spec: LogSpec):
         """The tier's engine for `spec`: the dispatch's own fused
@@ -480,10 +416,11 @@ class _FusedTier:
         return self.dispatch.fused_factory(spec)
 
     def _fused_engine(self):
-        """Lazily (re)build the tier's fused engine for the CURRENT
-        spec (fleet growth rebinds it). A factory rejection after a
-        shape change degrades the tier to off with a warning rather
-        than killing live traffic."""
+        """The engine to route a combiner round through, or None for
+        the ordinary chain (tier off). Lazily rebuilds the engine for
+        the CURRENT spec (fleet growth rebinds it); a factory rejection
+        after a shape change degrades the tier to off with a warning
+        rather than killing live traffic."""
         if self._fused_mode == "off":
             return None
         spec = self._fused_log_spec()
@@ -497,107 +434,8 @@ class _FusedTier:
                     "(%s); falling back to the ordinary chain", e
                 )
                 self._fused_mode = "off"
-                self._fused_choice = False
                 return None
         return self._fused
-
-    def _fused_calibrating(self, fkey: tuple | None = None) -> bool:
-        """Auto mode with no committed verdict for the CURRENT fence
-        mask — rounds are timed (and `defer` is ignored) while this
-        holds. A fenced mask whose engine has NO fenced variant
-        commits `chain` immediately: there is nothing to measure —
-        `_try_fused_round` would fall back unconditionally — and
-        without the short-circuit the fused side of the (pad, fkey)
-        key could never fill, leaving the wrapper 'calibrating' (defer
-        forced off, the serve pipeline's overlap dead) for the whole
-        quarantine. Callers on the round hot path pass the
-        already-computed `fkey` (the key derivation is an O(R) host
-        scan under the combiner lock — compute it once per round)."""
-        if self._fused_mode != "auto":
-            return False
-        if fkey is None:
-            fkey = self._fused_fence_key()
-        if self._fused_verdicts.get(fkey) is not None:
-            return False
-        if fkey:
-            eng = self._fused_engine()
-            if eng is None or not eng.supports_fenced:
-                self._fused_verdicts[fkey] = False
-                # every verdict commit leaves a trace record — an
-                # operator reading the calibrations section must be
-                # able to tell "measured chain win" from "nothing to
-                # measure under this mask"
-                get_tracer().emit(
-                    "fused-calibration", window=0, fenced=list(fkey),
-                    tier=self._fused_tier_name,
-                    devices=getattr(eng, "devices", 1),
-                    fused_s=0.0, chain_s=0.0, winner="chain",
-                    reason="no-fenced-variant",
-                )
-                return False
-        return True
-
-    def _fused_tier_wanted(self, pad: int,
-                           fkey: tuple | None = None):
-        """The engine to route a `pad`-window round through, or None
-        for the ordinary chain. During auto calibration the chain goes
-        first AT EACH (window, fence-mask) key (its programs are the
-        already-compiled steady state), then the fused tier collects
-        that key's own samples — mixing keys would compare
-        incomparable rounds. `fkey` as in `_fused_calibrating`."""
-        if self._fused_mode == "off" or self._fused_choice is False:
-            return None
-        if self._fused_mode == "auto":
-            if fkey is None:
-                fkey = self._fused_fence_key()
-            verdict = self._fused_verdicts.get(fkey)
-            if verdict is False:
-                return None
-            if verdict is None:
-                need = FUSED_CAL_WARMUP + FUSED_CAL_SAMPLES
-                chain = self._fused_samples["chain"].get(
-                    (pad, fkey), ()
-                )
-                if len(chain) < need:
-                    return None
-        return self._fused_engine()
-
-    def _note_fused_sample(self, tier: str, pad: int, dt: float,
-                           fkey: tuple = ()) -> None:
-        need = FUSED_CAL_WARMUP + FUSED_CAL_SAMPLES
-        key = (pad, tuple(fkey))
-        samples = self._fused_samples[tier].setdefault(key, [])
-        if len(samples) < need:
-            samples.append(dt)
-        # the verdict commits at the FIRST key whose chain and fused
-        # sides are both full: same-window same-mask samples only, and
-        # each side's warmup absorbed that program's compile
-        chain = self._fused_samples["chain"].get(key, ())
-        fused = self._fused_samples["pallas_fused"].get(key, ())
-        if len(chain) < need or len(fused) < need:
-            return
-        med_c = statistics.median(chain[FUSED_CAL_WARMUP:])
-        med_f = statistics.median(fused[FUSED_CAL_WARMUP:])
-        verdict = med_f <= med_c
-        self._fused_verdicts[tuple(fkey)] = verdict
-        get_tracer().emit(
-            "fused-calibration", window=pad, fenced=list(fkey),
-            tier=self._fused_tier_name,
-            devices=getattr(self._fused, "devices", 1),
-            fused_s=med_f, chain_s=med_c,
-            winner=(
-                self._fused_tier_name if verdict else "chain"
-            ),
-        )
-
-    def _reset_fused_calibration(self) -> None:
-        """Fleet-shape change (or mesh re-placement) under
-        engine='auto': the committed verdicts were measured at the OLD
-        (R, capacity, devices) point — drop them and recalibrate at
-        the new one."""
-        if self._fused_mode == "auto":
-            self._fused_verdicts = {}
-            self._fused_samples = {"pallas_fused": {}, "chain": {}}
 
     def round_tier(self, rid: int) -> str | None:
         """The engine tier that served replica `rid`'s most recent
@@ -616,22 +454,6 @@ class _FusedTier:
         downstream (`obs/` fleet tracing). Same per-rid discipline as
         `round_tier`."""
         return self._pos_by_rid.get(rid)
-
-    def _fused_tier_state(self) -> str:
-        """Human-readable fused-tier state for stats()/snapshot() —
-        the verdict for the CURRENT fence mask (auto mode verdicts are
-        per-mask, see `_fused_fence_key`)."""
-        if self._fused_mode == "off":
-            return "off"
-        if self._fused_mode == "forced":
-            return "forced"
-        verdict = self._fused_verdicts.get(self._fused_fence_key())
-        if verdict is None:
-            return "calibrating"
-        return (
-            f"auto:{self._fused_tier_name}" if verdict
-            else "auto:chain"
-        )
 
 
 class NodeReplicated(_FusedTier):
@@ -856,13 +678,14 @@ class NodeReplicated(_FusedTier):
         # the MESH-FUSED composition (`parallel/collectives.py:
         # MeshFusedEngine`): one shard_map-wrapped launch per device
         # with the cursor lattice joined over ICI, replacing the
-        # shmap/gspmd chain for eligible rounds. Mode resolution +
-        # winner-selection calibration: `_FusedTier` (shared with the
-        # CNR twin; initialized AFTER mesh normalization so the tier
-        # binds the real Mesh object). The tier never changes results —
-        # it is differentially pinned bit-identical to the scan engine
-        # (tests/test_pallas_fused.py, tests/test_mesh_fleet.py) —
-        # only the launch count.
+        # shmap/gspmd chain for eligible rounds. The tier runs only
+        # under engine='pallas'; 'auto' resolves to the chain above and
+        # never consults it. Validation + the lazy rebuild: `_FusedTier`
+        # (shared with the CNR twin; initialized AFTER mesh
+        # normalization so the tier binds the real Mesh object). The
+        # tier never changes results — it is differentially pinned
+        # bit-identical to the scan engine (tests/test_pallas_fused.py,
+        # tests/test_mesh_fleet.py) — only the launch count.
         self._init_fused_tier(engine, dispatch, self.mesh, reg, "nr",
                               debug=self.debug, mesh_fused=True)
         if self.mesh is not None:
@@ -880,9 +703,6 @@ class NodeReplicated(_FusedTier):
         from node_replication_tpu.parallel.mesh import place
 
         self.log, self.states = place(self.log, self.states, self.mesh)
-        # re-placement is a new (R, capacity, devices) point: an
-        # auto-mode winner verdict measured before it no longer applies
-        self._reset_fused_calibration()
 
     def replica_device(self, rid: int):
         """The device hosting replica `rid`'s state shard (None when
@@ -928,10 +748,8 @@ class NodeReplicated(_FusedTier):
         self._ring_fn = None
         self._ring_gather = None
         # the fused engine is spec-bound (R, capacity): rebuild lazily
-        # after any fleet-shape change — and an auto-mode verdict
-        # measured at the old shape no longer applies (recalibrate)
+        # after any fleet-shape change
         self._fused = None
-        self._reset_fused_calibration()
         dispatch = self.dispatch
         exec_fn = (
             partial(log_catchup_all, union=self._union)
@@ -1429,8 +1247,7 @@ class NodeReplicated(_FusedTier):
 
     @_locked
     def _try_fused_round(self, ops, rid, tids, n, pos0, pad,
-                         opcodes, args, pending=None,
-                         fkey: tuple = ()) -> bool:
+                         opcodes, args, pending=None) -> bool:
         """Route one combiner round through the fused engine when
         eligible; False falls back to the append+exec chain. The
         eligibility is exactly the lock-step precondition the fused
@@ -1445,10 +1262,8 @@ class NodeReplicated(_FusedTier):
         kernel is LAUNCHED and journaled here but the response
         readback (the round's host fence) is deferred to
         `_finish_round`: the whole device round overlaps whatever host
-        work the caller does between begin and finish. `fkey` is the
-        round's fence-mask calibration key, computed once by
-        `_begin_round`."""
-        eng = self._fused_tier_wanted(pad, fkey)
+        work the caller does between begin and finish."""
+        eng = self._fused_engine()
         if eng is None:
             return False
         if self._fenced is not None and not eng.supports_fenced:
@@ -1467,8 +1282,6 @@ class NodeReplicated(_FusedTier):
             self._m_fused_fallback.inc()
             return False
         # tail == pos0: the GC-help loop never appends
-        timing = self._fused_calibrating(fkey)
-        t0 = time.perf_counter()
         fenced = self._fenced
         extra = {"deferred": True} if pending is not None else {}
         if eng.tier == "mesh_fused":
@@ -1480,14 +1293,9 @@ class NodeReplicated(_FusedTier):
             )
             if pending is None:
                 # the response readback is also the round's host
-                # fence: delivery below needs the values, and the
-                # calibration timing needs completed device work
+                # fence: delivery below needs the values
                 resps_np = np.asarray(resps)
                 sp.fence(self.log, self.states)
-        if timing:
-            self._note_fused_sample(
-                "pallas_fused", pad, time.perf_counter() - t0, fkey
-            )
         if self._wal is not None:
             # same order as the chain: journal once the ops ARE in the
             # in-memory log, before any response is delivered
@@ -1535,9 +1343,7 @@ class NodeReplicated(_FusedTier):
         or, on the fused tier, the response readback of the
         already-launched kernel — for `finish`, so a pipelined caller
         overlaps the next batch's host work with this round's device
-        work. Calibration rounds (`engine='auto'`, verdict pending)
-        ignore `defer`: honest tier timing needs the round
-        back-to-back. The lock is reentrant: callers already hold it.
+        work. The lock is reentrant: callers already hold it.
 
         When the fused pallas tier is selected and the round is
         lock-step eligible, the whole round — append, replay, response
@@ -1570,21 +1376,13 @@ class NodeReplicated(_FusedTier):
         counters = self.dispatch.host_counters
         if counters is not None and get_registry().enabled:
             counters("append", ops)
-        fkey = self._fused_fence_key()  # once per round: O(R) scan
-        timing = self._fused_calibrating(fkey)
-        defer = defer and not timing
         pending = _PendingRound(rid, list(tids), n, pos0, batch=batch)
-        pending.pad = pad
-        pending.fkey = fkey
         if self._try_fused_round(ops, rid, tids, n, pos0, pad,
                                  opcodes, args,
-                                 pending if defer else None,
-                                 fkey=fkey):
+                                 pending if defer else None):
             if pending.fused_resps is None:
                 pending.done = True  # ran eagerly end-to-end
             return pending
-        if timing:
-            pending.t_chain = time.perf_counter()
         extra = {"batch": True} if batch else {}
         with span("append", rid=rid, n=n, pos0=pos0, **extra) as sp:
             self.log = self._append_call(opcodes, args, n)
@@ -1646,13 +1444,6 @@ class NodeReplicated(_FusedTier):
         self.last_round_tier = self.engine
         self._tier_by_rid[rid] = self.engine
         self._pos_by_rid[rid] = pending.pos0
-        if pending.t_chain is not None:
-            # the replay loop's cursor readbacks serialize the chain,
-            # so the wall delta is an honest device-time sample (keyed
-            # on the fence mask the round BEGAN under)
-            self._note_fused_sample("chain", pending.pad,
-                                    time.perf_counter()
-                                    - pending.t_chain, pending.fkey)
 
     @_locked
     def _append_and_replay(self, ops: list[tuple], rid: int,
@@ -1988,7 +1779,7 @@ class NodeReplicated(_FusedTier):
             "ring_rounds": self._ring_rounds,
             "engine": self.engine,
             "fused_rounds": self._fused_rounds,
-            "fused_tier": self._fused_tier_state(),
+            "fused_tier": self._fused_mode,
             "mesh_devices": self._mesh_shards,
             "max_lag": tail - int(ltails.min()),
         }
@@ -2031,7 +1822,7 @@ class NodeReplicated(_FusedTier):
                 "idle_rounds": self._idle_rounds,
                 "ring_rounds": self._ring_rounds,
                 "fused_rounds": self._fused_rounds,
-                "fused_tier": self._fused_tier_state(),
+                "fused_tier": self._fused_mode,
             },
             "mesh": (
                 # shard shape only: a per-rid device dict would be

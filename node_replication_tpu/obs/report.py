@@ -707,16 +707,13 @@ def analyze(events: list[dict]) -> dict:
     # kernels section: fused-round launches by tier, window-size
     # histogram, per-launch duration percentiles (kernel-launch events,
     # ops/pallas_*), plus serve batches by combiner engine (the
-    # serve-batch `engine` stamp) and the winner-selection verdicts
-    # (fused-calibration events, core/replica._FusedTier)
+    # serve-batch `engine` stamp)
     kernels = None
     klaunches = [e for e in events if e.get("event") == "kernel-launch"]
     serve_engines = [e.get("engine") for e in events
                      if e.get("event") == "serve-batch"
                      and e.get("engine")]
-    cals = [e for e in events
-            if e.get("event") == "fused-calibration"]
-    if klaunches or serve_engines or cals:
+    if klaunches or serve_engines:
         launch_by_tier: dict[str, int] = defaultdict(int)
         window_hist: dict[int, int] = defaultdict(int)
         kdurs = []
@@ -740,18 +737,6 @@ def analyze(events: list[dict]) -> dict:
             "serve_batches_by_engine": dict(
                 sorted(batches_by_engine.items())
             ),
-            "calibrations": [
-                {"winner": e.get("winner", "?"),
-                 "window": int(e.get("window", 0)),
-                 # mesh-aware + fence-keyed verdicts (ISSUE 15):
-                 # absent on pre-mesh traces, defaults keep old
-                 # artifacts renderable
-                 "devices": int(e.get("devices", 1) or 1),
-                 "fenced": list(e.get("fenced", []) or []),
-                 "fused_s": float(e.get("fused_s", 0.0)),
-                 "chain_s": float(e.get("chain_s", 0.0))}
-                for e in cals
-            ],
         }
 
     # sharding section: routing-tier map adoptions + typed refusals
@@ -1203,10 +1188,6 @@ def render(report: dict, out=None) -> None:
             w("  serve batches by engine: "
               + "   ".join(f"{k}={v}" for k, v in sorted(sbe.items()))
               + "\n")
-        for c in kernels["calibrations"]:
-            w(f"  winner selection @ window {c['window']}: "
-              f"{c['winner']} (fused {_fmt_s(c['fused_s'])} vs chain "
-              f"{_fmt_s(c['chain_s'])})\n")
 
     hb = report.get("host_budget")
     if hb:

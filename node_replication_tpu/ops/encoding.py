@@ -142,9 +142,10 @@ class Dispatch:
     # `round(log, states, opcodes, args, count, fenced=None)` under the
     # lock-step precondition, `supports(window)`, `launches(window)`,
     # and a `supports_fenced` class flag. Raising ValueError from the
-    # factory means "no fused form at this config" — wrappers fall
-    # back to the append+exec chain (`core/replica.py` winner
-    # selection).
+    # factory means "no fused form at this config": `engine="pallas"`
+    # refuses it at construction, and after a fleet-shape change the
+    # wrappers fall back to the append+exec chain (`core/replica.py`
+    # `_FusedTier`).
     fused_factory: Callable | None = None
     # Optional: `read_stacked(states, rid, opcode, args) -> int32[] or
     # int32[n]`, the read op of replica `rid` indexed in the stacked

@@ -14,8 +14,8 @@ Two contracts live here:
    (quarantined replicas skip state writeback and report zeroed
    responses, `fault/health.py`) all happen inside the kernel. The
    engine is the `log.engine.pallas_fused` tier `NodeReplicated` /
-   `MultiLogReplicated` route `_append_and_replay` rounds through when
-   winner selection picks it (`core/replica.py`), collapsing the
+   `MultiLogReplicated` route `_append_and_replay` rounds through under
+   `engine="pallas"` (`core/replica.py`), collapsing the
    host-sequenced encode → `log_append` → sort/merge → replay chain —
    and its per-round host syncs — into one launch per serve batch.
    Interpret-mode bit-identity vs the scan engine (ring wrap, fenced,

@@ -7,10 +7,11 @@ default tier for scan-engine models), and `make_ring_exec` backs the
 ring catch-up tier `NodeReplicated.sync()` takes for large uniform
 backlogs. `make_shmap_step` remains the fused lock-step batch path
 (`ShardedRunner`'s explicit twin and `__graft_entry__.dryrun_multichip`'s
-convergence probe), and `MeshFusedEngine` is the MESH-FUSED exec tier:
-the PR 10 one-launch fused append+replay round embedded in a shard_map
-program so a lock-step combiner round on an N-device fleet stays one
-launch per device with the cursor lattice joined over ICI. Per-tier
+convergence probe), and `MeshFusedEngine` is the MESH-FUSED exec tier
+(run only under `NodeReplicated(mesh=..., engine="pallas")`): the
+one-launch fused append+replay round embedded in a shard_map program so
+a lock-step combiner round on an N-device fleet stays one launch per
+device with the cursor lattice joined over ICI. Per-tier
 selection counters live next to the other engine tiers
 (`log.engine.shmap`, `log.engine.mesh_fused`, `nr.exec.engine.ring`,
 `nr.exec.mesh.*` — core/log.py, core/replica.py).
@@ -352,7 +353,9 @@ class MeshFusedEngine(FusedEngineHost):
     program over the replica mesh, so one combiner round on an
     N-device fleet is ONE shard_map-wrapped Pallas launch per device —
     issued as a single program — instead of the shmap tier's
-    append-program → exec-program chain.
+    append-program → exec-program chain. A meshed `NodeReplicated`
+    builds it only under `engine="pallas"`; `engine="auto"` keeps the
+    shmap/gspmd chain.
 
     Composition (the junction of the PR 9 and PR 10 tiers):
 

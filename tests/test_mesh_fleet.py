@@ -13,8 +13,8 @@ collective catch-up path. `TestMeshFused` extends the contract to the
 MESH-FUSED exec tier (`parallel/collectives.py:MeshFusedEngine`): one
 shard_map-wrapped Pallas launch per combiner round, pinned
 bit-identical to the un-meshed scan wrapper across ring wraps, a
-fence/repair cycle with the corpse on a non-zero shard, mesh-aware
-calibration resets, and depth-1 pipelined serve. This file is the CI
+fence/repair cycle with the corpse on a non-zero shard, and depth-1
+pipelined serve. This file is the CI
 `mesh-smoke` job (the mesh-fused half also rides `kernel-smoke`).
 """
 
@@ -410,39 +410,6 @@ class TestMeshFused:
         assert nr.stats()["fused_rounds"] == 4
         assert int(np.asarray(nr.log.ltails)[2]) == frozen
         assert int(nr.log.head) > frozen  # GC not stalled by the corpse
-
-    def test_grow_resets_calibration_at_devices_key(self, monkeypatch):
-        # mesh-aware winner selection: the verdict is measured at the
-        # live (R, capacity, devices) point — the fused-calibration
-        # event carries devices=, and growth recalibrates
-        monkeypatch.setenv("NR_TPU_FUSED_CAL", "1")
-        from node_replication_tpu.obs.recorder import get_tracer
-
-        mesh = replica_mesh(4)
-        t = get_tracer()
-        t.enable(None)
-        try:
-            nr = NodeReplicated(make_hashmap(17), n_replicas=8,
-                                log_entries=512, gc_slack=64,
-                                engine="auto", mesh=mesh)
-            assert nr.stats()["fused_tier"] == "calibrating"
-            for i in range(8):
-                nr.execute_mut_batch(
-                    [(HM_PUT, i % 17, i), (HM_PUT, (i + 5) % 17, i)],
-                    rid=0,
-                )
-            st = nr.stats()
-            assert st["fused_tier"] in ("auto:mesh_fused",
-                                        "auto:chain"), st
-            cal = [e for e in t.events()
-                   if e["event"] == "fused-calibration"]
-            assert cal and cal[-1]["devices"] == 4
-            assert cal[-1]["tier"] == "mesh_fused"
-            assert cal[-1]["winner"] in ("mesh_fused", "chain")
-            nr.grow_fleet(4)
-            assert nr.stats()["fused_tier"] == "calibrating"
-        finally:
-            t.disable()
 
     def test_vspace_mesh_fused_and_fenced_fallback(self):
         # the second fused model rides the same factory composition:

@@ -490,14 +490,19 @@ class TestReportRobustness:
         assert rep["fault"]["repair_p50_s"] == 0.0
         assert "== fault ==" in out
 
-    def test_kernel_calibration_only(self):
+    def test_kernel_launch_only(self):
         rep, out = self._render(
-            [{"event": "fused-calibration", "winner": "chain",
-              "window": 64, "fused_s": 0.2, "chain_s": 0.1,
-              "ts": 1.0, "mono": 1.0}]
+            [{"event": "kernel-launch", "tier": "pallas_fused",
+              "window": 64, "count": 40, "launches": 1,
+              "duration_s": 0.002, "ts": 1.0, "mono": 1.0}]
         )
-        assert rep["kernels"]["calibrations"][0]["winner"] == "chain"
+        k = rep["kernels"]
+        assert k["rounds"] == 1 and k["fused_ops"] == 40
+        assert k["launches_by_tier"] == {"pallas_fused": 1}
+        assert k["window_hist"] == {64: 1}
+        assert k["serve_batches_by_engine"] == {}
         assert "== kernels ==" in out
+        assert "launches by tier: pallas_fused=1" in out
 
     def test_durability_open_only(self):
         rep, out = self._render(

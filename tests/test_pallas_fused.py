@@ -4,12 +4,15 @@ The fused pallas combiner round (`ops/pallas_replay.FusedHashmapEngine`,
 `ops/pallas_vspace.FusedVspaceEngine`) must be BIT-IDENTICAL to the scan
 engine across every path it replaces: plain batches, NOOP padding,
 ring-wrap windows, fenced replicas, the wrapper batch entry point, and
-the CNR per-log sub-batch path — plus the winner-selection routing
-(`core/replica._FusedTier`) asserted via the `log.engine.*` /
+the CNR per-log sub-batch path — plus the tier's routing
+(`core/replica._FusedTier`: forced by `engine="pallas"`, never consulted
+by `engine="auto"`) asserted via the `log.engine.*` /
 `nr.exec.engine.*` counters, and a serve round-trip whose `serve-batch`
 events carry the engine tier. `bench.py --kernel --kernel-interpret` is
 the CI twin of the bit-identity half (kernel-smoke job).
 """
+
+import dataclasses
 
 import numpy as np
 import pytest
@@ -312,46 +315,92 @@ class TestWrapperTier:
                            engine="pallas", debug=True)
 
 
-class TestAutoWinnerSelection:
+def _vspace_ops(rng, n, pages):
+    ops = []
+    for _ in range(n):
+        if rng.rand() < 0.7:
+            ops.append((1, int(rng.randint(pages)),
+                        int(rng.randint(1, 1000)),
+                        int(rng.randint(0, 12))))
+        else:
+            ops.append((2, int(rng.randint(pages)),
+                        int(rng.randint(0, 12))))
+    return ops
+
+
+class TestAutoEngine:
+    """`engine='auto'` is the chain on every backend: the fused tier
+    runs only where the caller forces it with `engine='pallas'`."""
+
     def test_cpu_default_keeps_tier_off(self):
         nr = NodeReplicated(make_hashmap(8), n_replicas=2,
                             log_entries=512, gc_slack=64, engine="auto")
         assert nr.stats()["fused_tier"] == "off"
 
-    def test_calibration_routes_by_measured_winner(self, monkeypatch):
-        monkeypatch.setenv("NR_TPU_FUSED_CAL", "1")
-        reg = get_registry()
-        reg.enable()
-        fused_c = reg.counter("log.engine.pallas_fused")
-        before = fused_c.value
-        nr = NodeReplicated(make_hashmap(17), n_replicas=2,
-                            log_entries=512, gc_slack=64, engine="auto")
-        assert nr.stats()["fused_tier"] == "calibrating"
-        t = nr.register(0)
-        for i in range(8):
-            nr.execute_mut((1, i % 17, i), t)
-        st = nr.stats()
-        # both tiers ran real rounds during calibration...
-        cal_fused = fused_c.value - before
-        assert cal_fused == 3  # WARMUP + SAMPLES
-        assert st["exec_rounds"] >= 3
-        assert st["fused_tier"] in ("auto:pallas_fused", "auto:chain")
-        # ...and post-decision rounds route ONLY to the winner
-        mark_fused = fused_c.value
-        mark_exec = nr.stats()["exec_rounds"]
-        for i in range(4):
-            nr.execute_mut((1, i, i), t)
-        if st["fused_tier"] == "auto:pallas_fused":
-            assert fused_c.value - mark_fused == 4
-            assert nr.stats()["exec_rounds"] == mark_exec
-        else:
-            assert fused_c.value == mark_fused
-            assert nr.stats()["exec_rounds"] > mark_exec
+    @pytest.mark.parametrize("case", [
+        "hashmap", "vspace", "hashmap-fenced", "hashmap-mesh2",
+        "hashmap-grow", "cnr-hashmap",
+    ])
+    def test_auto_never_consults_fused_tier(self, case, monkeypatch):
+        # on a TPU backend too: no round, batch or split, may build or
+        # consult the model's fused engine, and every answer matches
+        # the scan twin's
+        from node_replication_tpu.core.cnr import MultiLogReplicated
+        from node_replication_tpu.models.vspace import make_vspace
 
-    def test_kernel_compile_error_surfaces(self, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+        def consulted(*args, **kwargs):
+            pytest.fail("engine='auto' consulted the fused tier")
+
+        rng = np.random.RandomState(11)
+        if case == "vspace":
+            model = lambda: make_vspace(512, max_span=8)
+            gen = lambda n: _vspace_ops(rng, n, 512)
+        else:
+            model = lambda: make_hashmap(29)
+            gen = lambda n: _mixed_ops(rng, n, 29)
+        kw = dict(n_replicas=4, log_entries=512, gc_slack=64)
+        auto_model = dataclasses.replace(model(), fused_factory=consulted)
+        if case == "cnr-hashmap":
+            mapper = lambda opc, args: args[0]
+            nr = MultiLogReplicated(auto_model, mapper, nlogs=2,
+                                    engine="auto", **kw)
+            twin = MultiLogReplicated(model(), mapper, nlogs=2,
+                                      engine="scan", **kw)
+        else:
+            mesh = 2 if case == "hashmap-mesh2" else None
+            nr = NodeReplicated(auto_model, engine="auto", mesh=mesh,
+                                **kw)
+            twin = NodeReplicated(model(), engine="scan", **kw)
+        if case == "hashmap-fenced":
+            for w in (nr, twin):
+                w.fence_replica(3)
+        ops = gen(12)
+        assert nr.execute_mut_batch(ops, rid=0) == \
+            twin.execute_mut_batch(ops, rid=0)
+        if case == "hashmap-grow":
+            for w in (nr, twin):
+                w.grow_fleet(1)
+        ops = gen(12)
+        p = nr.begin_mut_batch(ops, rid=1)
+        assert nr.finish_mut_batch(p) == \
+            twin.execute_mut_batch(ops, rid=1)
+        # per-op rounds: five of one window, back to back
+        t, t_twin = nr.register(2), twin.register(2)
+        for op in gen(5):
+            assert nr.execute_mut(op, t) == twin.execute_mut(op, t_twin)
+        for k in (0, 5, 22):
+            assert nr.execute((1, k), t) == twin.execute((1, k), t_twin)
+        st = nr.stats()
+        assert st["fused_tier"] == "off"
+        assert st["fused_rounds"] == 0
+        assert nr.round_tier(2) not in ("pallas_fused", "mesh_fused")
+
+    def test_kernel_compile_error_surfaces(self):
         # a Mosaic refusal at the fused round's compile is an error,
         # not a shape rejection: it must reach the caller and leave
-        # the tier calibrating — never a silent switch to the chain
+        # the tier forced — never a silent, counted switch to the chain
         from jax._src.pallas.mosaic.error_handling import MosaicError
 
         from node_replication_tpu.ops.pallas_replay import (
@@ -363,119 +412,23 @@ class TestAutoWinnerSelection:
                 raise MosaicError("Mosaic failed to compile TPU kernel")
             return fn
 
-        monkeypatch.setenv("NR_TPU_FUSED_CAL", "1")
-        monkeypatch.setattr(FusedHashmapEngine, "round_fn", refused)
-        fb = get_registry().counter("nr.exec.engine.fused_fallback")
+        reg = get_registry()
+        reg.enable()
+        fb = reg.counter("nr.exec.engine.fused_fallback")
         nr = NodeReplicated(make_hashmap(17), n_replicas=2,
-                            log_entries=512, gc_slack=64, engine="auto")
-        t = nr.register(0)
-        with pytest.raises(MosaicError):
-            for i in range(8):  # chain samples first, then the kernel
-                nr.execute_mut((1, i, i), t)
-        before = fb.value
-        assert nr.stats()["fused_tier"] == "calibrating"
-        with pytest.raises(MosaicError):
-            nr.execute_mut((1, 9, 9), t)
-        assert fb.value == before
-
-    def test_samples_are_per_window(self, monkeypatch):
-        # chain/fused timings only compare at the SAME padded window
-        # (and the same fence mask — the key's second half): a
-        # different batch size must not satisfy another window's
-        # calibration quota
-        monkeypatch.setenv("NR_TPU_FUSED_CAL", "1")
-        nr = NodeReplicated(make_hashmap(17), n_replicas=2,
-                            log_entries=512, gc_slack=64, engine="auto")
-        nr.execute_mut_batch([(1, 1, 1)], rid=0)          # pad 1
-        nr.execute_mut_batch([(1, 1, 1), (1, 2, 2)], rid=0)  # pad 2
-        assert (1, ()) in nr._fused_samples["chain"]
-        assert (2, ()) in nr._fused_samples["chain"]
-        assert len(nr._fused_samples["chain"][(1, ())]) == 1
-        assert nr.stats()["fused_tier"] == "calibrating"
-
-    def test_verdict_rekeys_on_fence_mask(self, monkeypatch):
-        # satellite regression (ISSUE 15): a verdict committed from
-        # UNFENCED rounds must not route fenced rounds through a tier
-        # whose fenced variant was never timed — samples and verdicts
-        # key on the fence mask, so a quarantine mid-serve
-        # recalibrates (second fused-calibration event, fenced key),
-        # and unfencing restores the original measured verdict
-        monkeypatch.setenv("NR_TPU_FUSED_CAL", "1")
-        from node_replication_tpu.obs.recorder import get_tracer
-
-        t = get_tracer()
-        t.enable(None)
-        try:
-            nr = NodeReplicated(make_hashmap(17), n_replicas=4,
-                                log_entries=512, gc_slack=64,
-                                engine="auto")
-            tok = nr.register(0)
-            for i in range(8):
-                nr.execute_mut((1, i % 17, i), tok)
-            st = nr.stats()
-            assert st["fused_tier"] in ("auto:pallas_fused",
-                                        "auto:chain")
-            cal = [e for e in t.events()
-                   if e["event"] == "fused-calibration"]
-            assert len(cal) == 1 and cal[0]["fenced"] == []
-            nr.fence_replica(2)
-            # the unfenced verdict does NOT carry over the mask change
-            assert nr.stats()["fused_tier"] == "calibrating"
-            for i in range(8):
-                nr.execute_mut((1, i % 17, i + 100), tok)
-            cal = [e for e in t.events()
-                   if e["event"] == "fused-calibration"]
-            assert len(cal) == 2 and cal[1]["fenced"] == [2]
-            assert nr.stats()["fused_tier"] in ("auto:pallas_fused",
-                                                "auto:chain")
-            # unfence: the original unfenced-mask verdict still stands
-            nr.clone_replica_from(2, donor=0)
-            nr.unfence_replica(2)
-            assert nr.stats()["fused_tier"] == st["fused_tier"]
-        finally:
-            t.disable()
-
-    def test_fenced_mask_without_fenced_variant_commits_chain(
-            self, monkeypatch):
-        # an engine with no fenced kernel variant (flat vspace) has
-        # nothing to measure under a quarantine mask: the verdict must
-        # commit to chain immediately, not sit 'calibrating' forever
-        # (which would force defer off and kill the serve pipeline's
-        # overlap for the whole quarantine)
-        monkeypatch.setenv("NR_TPU_FUSED_CAL", "1")
-        from node_replication_tpu.models.vspace import make_vspace
-
-        nr = NodeReplicated(make_vspace(512, max_span=8), n_replicas=3,
                             log_entries=512, gc_slack=64,
-                            engine="auto")
-        for i in range(8):
-            nr.execute_mut_batch([(1, i, i + 1, 2),
-                                  (1, i + 9, i, 2)], rid=0)
-        assert nr.stats()["fused_tier"] in ("auto:pallas_fused",
-                                            "auto:chain")
-        nr.fence_replica(2)
-        nr.execute_mut_batch([(1, 3, 7, 1)], rid=0)
-        # committed (to chain), NOT stuck calibrating — and the split
-        # round still defers under the quarantine
-        assert nr.stats()["fused_tier"] == "auto:chain"
-        p = nr.begin_mut_batch([(1, 5, 6, 1)], rid=0)
-        assert p.done is False  # deferred, not forced serial by timing
-        assert nr.finish_mut_batch(p) == [0]
-
-    def test_grow_fleet_resets_calibration(self, monkeypatch):
-        # a committed verdict was measured at the OLD (R, capacity)
-        # point; growth must recalibrate, not keep routing on it
-        monkeypatch.setenv("NR_TPU_FUSED_CAL", "1")
-        nr = NodeReplicated(make_hashmap(17), n_replicas=2,
-                            log_entries=512, gc_slack=64, engine="auto")
-        t = nr.register(0)
-        for i in range(8):
-            nr.execute_mut((1, i % 17, i), t)
-        assert nr.stats()["fused_tier"] in (
-            "auto:pallas_fused", "auto:chain"
-        )
-        nr.grow_fleet(1)
-        assert nr.stats()["fused_tier"] == "calibrating"
+                            engine="pallas")
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(FusedHashmapEngine, "round_fn", refused)
+            before = fb.value
+            with pytest.raises(MosaicError):
+                nr.execute_mut((1, 1, 1), nr.register(0))
+            with pytest.raises(MosaicError):
+                nr.execute_mut_batch([(1, 2, 2)], rid=1)
+            assert fb.value == before
+        st = nr.stats()
+        assert st["fused_tier"] == "forced"
+        assert st["fused_rounds"] == 0
 
 
 class TestCNRFused:
@@ -554,16 +507,7 @@ class TestVspaceFused:
         nr_s = NodeReplicated(make_vspace(P, max_span=8), n_replicas=2,
                               log_entries=512, gc_slack=64,
                               engine="scan")
-        rng = np.random.RandomState(7)
-        ops = []
-        for _ in range(20):
-            if rng.rand() < 0.7:
-                ops.append((1, int(rng.randint(P)),
-                            int(rng.randint(1, 1000)),
-                            int(rng.randint(0, 12))))
-            else:
-                ops.append((2, int(rng.randint(P)),
-                            int(rng.randint(0, 12))))
+        ops = _vspace_ops(np.random.RandomState(7), 20, P)
         assert nr_f.execute_mut_batch(ops, rid=0) == \
             nr_s.execute_mut_batch(ops, rid=0)
         assert nr_f.stats()["fused_rounds"] > 0
@@ -592,6 +536,28 @@ class TestVspaceFused:
         nr.execute_mut_batch([(1, 8, 9, 4)], rid=0)
         assert fb.value > before
         assert nr.last_round_tier == nr.engine
+
+    def test_fenced_split_round_defers(self):
+        # with no fenced variant a fenced fleet's split round takes the
+        # chain, and the chain still defers its replay to finish and
+        # answers as the scan twin does
+        from node_replication_tpu.models.vspace import make_vspace
+
+        nr, twin = (
+            NodeReplicated(make_vspace(512, max_span=8), n_replicas=3,
+                           log_entries=512, gc_slack=64, engine=engine)
+            for engine in ("pallas", "scan")
+        )
+        for w in (nr, twin):
+            w.execute_mut_batch([(1, 0, 7, 4)], rid=0)
+            w.fence_replica(2)
+        ops = [(1, 5, 6, 1), (1, 2, 9, 2)]
+        p = nr.begin_mut_batch(ops, rid=0)
+        assert p.done is False
+        assert nr.finish_mut_batch(p) == \
+            twin.execute_mut_batch(ops, rid=0)
+        assert nr.last_round_tier == nr.engine
+        assert nr.stats()["fused_tier"] == "forced"
 
 
 class TestMkbenchKernel:

@@ -200,12 +200,11 @@ class TestWideRead:
         got = nr.execute((HM_GET, 3), nr.register(1))
         assert isinstance(got, np.ndarray) and got.tolist() == [42, HM_GET]
 
-    def test_no_fused_tier_is_consulted(self, monkeypatch):
-        monkeypatch.setenv("NR_TPU_FUSED_CAL", "1")  # would arm calibration
+    def test_no_fused_tier_is_consulted(self):
         nr = NodeReplicated(make_records(64), n_replicas=2, engine="auto")
         assert nr._fused_mode == "off" and nr.engine == "combined"
         nr.execute_mut_batch([(REC_UPDATE, 1, 1, *range(25))] * 3, rid=0)
-        assert nr._fused_tier_wanted(4) is None
+        assert nr._fused_engine() is None
         assert nr.round_tier(0) == "combined"
 
 
