@@ -68,7 +68,8 @@ TRACING_CALLS = frozenset({
 # window functions (`make_state` runs eagerly at init and is excluded).
 DISPATCH_FN_KWARGS = frozenset({
     "write_ops", "read_ops", "window_apply", "window_plan",
-    "window_merge", "window_merge_stacked", "read_stacked",
+    "window_merge", "window_merge_stacked", "window_apply_stacked",
+    "read_stacked",
 })
 DISPATCH_FN_POSARGS = (2, 3)  # (name, make_state, write_ops, read_ops)
 

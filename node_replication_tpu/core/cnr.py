@@ -10,16 +10,21 @@ targets one (`cnr/src/replica.rs:579-597`). The per-log GC-starvation
 callback (`cnr/src/log.rs:135-142`) fires as `gc_callback(log_idx,
 dormant_replica)` from the host-side watchdog when a log's replay stalls.
 
-The jit-hot batch path is `core/multilog.make_multilog_step`; this wrapper
-is the per-op convenience with the same replay kernels underneath.
+A combiner round is one hold of the lock, as `NodeReplicated`'s is: its
+ops are routed through the `LogMapper`, every touched log's sub-batch is
+appended by one program (`jit_cnr_append`), and one program replays every
+log into every replica (`jit_cnr_exec`, `core/multilog.multilog_exec_window`);
+the logs are data to both, so no program is compiled per log. Reads are
+`NodeReplicated`'s combined reads (`core/replica._ReadCombining`), each
+synced on its mapped log only.
+
+The jit-hot batch path is `core/multilog.make_multilog_step`.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import logging
-import threading
-
-from node_replication_tpu.analysis.locks import make_rlock
 import time
 from collections import deque
 from functools import partial
@@ -29,16 +34,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from node_replication_tpu.core.log import (
-    LogSpec,
-    LogState,
-    WARN_ROUNDS,
-    host_cursors,
-)
+from node_replication_tpu.analysis.locks import make_rlock
+from node_replication_tpu.core.log import LogSpec, LogState, WARN_ROUNDS
 from node_replication_tpu.core.multilog import (
     LogMapper,
     MultiLogSpec,
-    _exec_one_log,
+    multilog_append,
+    multilog_exec_window,
     multilog_init,
 )
 from node_replication_tpu.core.replica import (
@@ -46,37 +48,51 @@ from node_replication_tpu.core.replica import (
     MAX_THREADS_PER_REPLICA,
     LogTooSmallError,
     ReplicaToken,
+    _batch_fields,
     _FusedTier,
     _locked,
-    _PendingRound,
+    _locked_phase,
+    _named,
+    _pending_fields,
+    _read_fields,
+    _ReadCombining,
+    _ReadSlot,
     replicate_state,
     states_equal,
 )
 from node_replication_tpu.fault.inject import fault_hook
 from node_replication_tpu.obs.metrics import COUNT_BUCKETS, get_registry
-from node_replication_tpu.ops.encoding import Dispatch, apply_read, encode_ops
 from node_replication_tpu.obs.recorder import get_tracer, span
+from node_replication_tpu.ops.encoding import NOOP, Dispatch, apply_read
 
 logger = logging.getLogger("node_replication_tpu")
 
 
-class _PendingCnrBatch:
-    """One `begin_mut_batch` batch between begin and finish: the
-    per-log `_PendingRound` sub-rounds (in the log order they were
-    appended) plus the scatter map back to submission indices. The
-    CNR face of the split-round protocol (`core/replica.py:
-    _PendingRound`); NOT atomic across logs, like the serial path."""
+class _PendingCnrRound:
+    """One CNR combiner round between `_begin_round` and `_finish_round`
+    (the `core/replica._PendingRound` twin): the replica, the number of
+    ops, each touched log's target position (its pre-append tail plus its
+    sub-batch), and, on the fused tier, the per-log launches whose
+    responses await readback. After begin every op is APPENDED, on every
+    touched log. `out` collects a batch's responses in submission order;
+    `pos0` is the last touched log's pre-append tail (the serve layer's
+    trace join key, `round_pos`)."""
 
-    __slots__ = ("rid", "n", "subs")
+    __slots__ = ("rid", "n", "targets", "pos0", "fused", "done", "out")
 
     def __init__(self, rid: int, n: int):
         self.rid = rid
         self.n = n
-        #: list of (log_idx, submission_indices, _PendingRound)
-        self.subs: list[tuple[int, list[int], _PendingRound]] = []
+        #: [(log, target position)]
+        self.targets: list[tuple[int, int]] = []
+        self.pos0 = 0
+        #: [(log, op indices, device responses)] of deferred fused launches
+        self.fused: list | None = None
+        self.done = False
+        self.out: list | None = None
 
 
-class MultiLogReplicated(_FusedTier):
+class MultiLogReplicated(_ReadCombining, _FusedTier):
     """N replicas of one `Dispatch` behind L commutativity-partitioned logs."""
 
     def __init__(
@@ -155,23 +171,28 @@ class MultiLogReplicated(_FusedTier):
                                "gspmd")
             self.ml, self.states = place(self.ml, self.states, mesh)
 
-        # Combiner lock (`replica._locked`): one combiner pass at a
-        # time across all logs; reentrant so watchdog gc_callbacks can
+        # Combiner lock (`replica._locked`): one combiner round at a time
+        # across all logs; reentrant so watchdog gc_callbacks can
         # re-enter sync_log on the same thread.
         self._lock = make_rlock("MultiLogReplicated._lock")
+        self._init_read_combining()
         self._threads_per_replica = [0] * n_replicas
         # staged ops: (rid, tid) -> deque[(log, opcode, args)]
         self._pending: dict[tuple[int, int], deque] = {}
-        # appended-but-unanswered: (rid, log) -> deque[(pos, tid)]
+        # appended-but-unanswered: (rid, log) -> deque[(pos, tid, index)],
+        # `index` placing a batch op's response in its batch's `out`
         self._inflight: dict[tuple[int, int], deque] = {}
         # delivered responses per thread, in enqueue order per log
         self._resps: dict[tuple[int, int], deque] = {}
+        # the responses of each replica's batch in flight, by submission
+        # index (`execute_mut_batch`, `begin_mut_batch`)
+        self._batch_out: dict[int, list] = {}
         # split-round registry (`begin_mut_batch`): at most ONE
-        # begun-but-unfinished batch per replica (the NodeReplicated
-        # invariant, here spanning the batch's per-log sub-rounds)
-        self._pending_batch: dict[int, "_PendingCnrBatch"] = {}
+        # begun-but-unfinished round per replica (the NodeReplicated
+        # invariant)
+        self._pending_batch: dict[int, _PendingCnrRound] = {}
         # per-log observability: LogMapper routing counts, combiner
-        # passes, replay rounds (+ idle skips) per log
+        # rounds that appended to each log, replay rounds (+ idle skips)
         self._log_selected = [0] * nlogs
         self._combine_rounds = [0] * nlogs
         self._exec_rounds = 0
@@ -182,14 +203,16 @@ class MultiLogReplicated(_FusedTier):
         self._m_combine = reg.counter("cnr.combine.rounds")
         self._m_batch = reg.histogram("cnr.combine.batch_size",
                                       buckets=COUNT_BUCKETS)
+        self._m_logs = reg.histogram("cnr.round.logs",
+                                     buckets=COUNT_BUCKETS)
         self._m_stalls = reg.counter("cnr.watchdog.stalls")
 
         # ---- fused pallas per-log combiner tier (the NodeReplicated
-        # twin, `core/replica._FusedTier`): a per-log sub-batch whose
-        # log is lock-step eligible appends+replays+answers as ONE
-        # kernel launch. Only engine='pallas' runs it; 'auto' and
-        # 'scan' keep the chain and never consult it. CNR has no
-        # fencing, so the fenced kernel variant never builds here.
+        # twin, `core/replica._FusedTier`): a round whose touched logs
+        # are all lock-step eligible appends+replays+answers each log's
+        # sub-batch as ONE kernel launch. Only engine='pallas' runs it;
+        # 'auto' and 'scan' never consult it. CNR has no fencing, so
+        # the fenced kernel variant never builds here.
         if engine not in ("auto", "scan", "pallas"):
             raise ValueError(f"unknown engine {engine!r}")
         self._fused_cnr_cache: dict = {}
@@ -198,53 +221,41 @@ class MultiLogReplicated(_FusedTier):
             self._m_mesh_round = reg.counter("cnr.exec.mesh.gspmd")
             self._m_mesh_sync_bytes = reg.counter("mesh.sync_bytes")
 
-        spec, d = self.spec, dispatch
-
-        def exec_round(ml, states, log_idx: int, window: int):
-            states, resps, lt = jax.vmap(
-                lambda s, t: _exec_one_log(
-                    spec, d, ml.opcodes[log_idx], ml.args[log_idx],
-                    ml.tail[log_idx], s, t, window,
-                )
-            )(states, ml.ltails[log_idx])
-            ml = ml._replace(
-                ltails=ml.ltails.at[log_idx].set(lt),
-                ctail=ml.ctail.at[log_idx].set(
-                    jnp.maximum(ml.ctail[log_idx], jnp.max(lt))
-                ),
-                head=ml.head.at[log_idx].set(jnp.min(lt)),
-            )
-            return ml, states, resps
-
+        # The round's two programs. Replay: the model's
+        # `window_apply_stacked` or `window_apply` over every log's window
+        # (the hashmap scatters each key's last write), or, for
+        # engine='scan' and models with neither, the scan of
+        # `apply_write` (`multilog_exec_window`).
+        d = dispatch
+        if engine == "scan" or (d.window_apply is None
+                                and d.window_apply_stacked is None):
+            d = dataclasses.replace(d, window_apply=None,
+                                    window_apply_stacked=None)
+            self.engine = "scan"
+        else:
+            self.engine = "combined"
+        spec = self.spec
         self._exec_jit = jax.jit(
-            exec_round, static_argnames=("log_idx", "window"),
-            donate_argnums=(0, 1),
+            _named(partial(multilog_exec_window, spec, d), "cnr_exec"),
+            static_argnames=("window",), donate_argnums=(0, 1),
         )
-
-        def append_one(ml, log_idx: int, opcodes, args, count):
-            B = opcodes.shape[0]
-            lanes = jnp.arange(B, dtype=jnp.int64)
-            valid = lanes < count
-            slot = jnp.where(
-                valid, (ml.tail[log_idx] + lanes) & spec.mask, spec.capacity
-            ).astype(jnp.int32)
-            return ml._replace(
-                opcodes=ml.opcodes.at[log_idx, slot].set(
-                    opcodes, mode="drop"
-                ),
-                args=ml.args.at[log_idx, slot].set(args, mode="drop"),
-                tail=ml.tail.at[log_idx].add(count),
-            )
-
         self._append_jit = jax.jit(
-            append_one, static_argnames=("log_idx",), donate_argnums=(0,)
+            _named(partial(multilog_append, spec), "cnr_append"),
+            donate_argnums=(0,),
         )
 
+        # The read program: `read_stacked`, one slot of the stacked
+        # replicas, where the model has it (combined by `execute`);
+        # else `read_one`, which slices replica `rid` out whole.
         def read_one(states, rid, opcode, args):
             state = jax.tree.map(lambda a: a[rid], states)
-            return apply_read(d, state, opcode, args)
+            return apply_read(dispatch, state, opcode, args)
 
-        self._read_jit = jax.jit(read_one)
+        if dispatch.read_stacked is None:
+            self._read_jit = jax.jit(read_one)
+        else:
+            self._read_jit = self._read_stacked_jit = jax.jit(
+                _named(partial(dispatch.read_stacked), "read_stacked"))
 
     # ------------------------------------------------------------------ API
 
@@ -298,8 +309,8 @@ class MultiLogReplicated(_FusedTier):
         h = self.enqueue_mut(op, token)
         self.combine(token.rid, h)
         # The combine delivered this op's response last (it is the
-        # thread's newest append on its log); pop it from the tail so
-        # earlier enqueued-but-unread responses stay for `responses()`.
+        # thread's newest append); pop it from the tail so earlier
+        # enqueued-but-unread responses stay for `responses()`.
         q = self._resps[(token.rid, token.tid)]
         return q.pop() if q else None
 
@@ -317,15 +328,10 @@ class MultiLogReplicated(_FusedTier):
 
     @_locked
     def flush(self, rid: int | None = None) -> None:
-        """Combine every log with staged ops (all replicas by default)."""
+        """Combine every staged op, every log's, as one round per
+        replica (all replicas by default)."""
         for r in range(self.n_replicas) if rid is None else [rid]:
-            logs = {
-                h
-                for tid in range(self._threads_per_replica[r])
-                for (h, _, _) in self._pending[(r, tid)]
-            }
-            for h in sorted(logs):
-                self.combine(r, h)
+            self._combine(r, None)
 
     @_locked
     def responses(self, token: ReplicaToken) -> list:
@@ -336,49 +342,69 @@ class MultiLogReplicated(_FusedTier):
         q.clear()
         return out
 
-    @_locked
-    def execute(self, op: tuple, token: ReplicaToken):
-        """Read path: sync only the mapped log, then dispatch locally
-        (`cnr/src/replica.rs:599-617`)."""
+    @_locked_phase("read", _read_fields)
+    def _execute_one(self, op: tuple, token: ReplicaToken):
+        """A read alone, under its own hold: sync only the mapped log,
+        then dispatch locally (`cnr/src/replica.rs:599-617`). Every read
+        of a model without `read_stacked` takes it, and any read by a
+        caller that holds the lock already (`_ReadCombining.execute`)."""
         h = self._map(op)
-        rid = token.rid
-        fault_hook("read-sync", rid, self)
-        ctail = int(np.asarray(self.ml.ctail)[h])
+        fault_hook("read-sync", token.rid, self)
+        self._sync_read_logs([(h, token.rid)])
+        return self._dispatch_read(token.rid, op)
+
+    def _read_refused(self, rid: int) -> BaseException | None:
+        return None  # CNR fences no replica
+
+    def _sync_reads(self, live: list[_ReadSlot]) -> None:
+        """The read combiner's sync: each read's mapped log, on its
+        replica, to that log's completed tail."""
+        self._sync_read_logs([(self._map(s.op), s.rid) for s in live])
+
+    @_locked
+    def _sync_read_logs(self, pairs: list[tuple[int, int]]) -> None:
+        """Replay until replica `r` has applied log `h` up to the
+        completed tail `h` had when the reads were taken, for every
+        `(h, r)`: the cursors are fetched once, and each replay round
+        replays every log."""
+        lt, ctail = jax.device_get((self.ml.ltails, self.ml.ctail))
         rounds = 0
-        while int(np.asarray(self.ml.ltails)[h, rid]) < ctail:
-            self._exec_round(h)
-            rounds = self._watchdog(rounds, h, "read-sync")
-        args = np.zeros((self.spec.arg_width,), np.int32)
-        args[: len(op) - 1] = op[1:]
-        return int(
-            self._read_jit(
-                self.states, jnp.int32(rid), jnp.int32(op[0]),
-                jnp.asarray(args),
-            )
-        )
+        while True:
+            lagging = [h for h, r in pairs if lt[h, r] < ctail[h]]
+            if not lagging:
+                return
+            lt = self._exec_round()
+            rounds = self._watchdog(rounds, lagging[0], "read-sync")
 
     @_locked
     def combine(self, rid: int, log_idx: int) -> None:
         """Drain replica `rid`'s staged ops for `log_idx` (thread order),
-        append them to that log, and replay it until `rid` has applied its
+        append them to that log, and replay until `rid` has applied its
         own ops — one log's combiner pass (`cnr/src/replica.rs:673-720`)."""
+        self._combine(rid, log_idx)
+
+    @_locked
+    def _combine(self, rid: int, log_idx: int | None) -> None:
+        """`combine` of one log, or of every log (None) in one round."""
         ops: list[tuple] = []  # (opcode, *args)
         tids: list[int] = []
+        logs: list[int] = []
         for tid in range(self._threads_per_replica[rid]):
             q = self._pending[(rid, tid)]
             keep = deque()
             while q:
                 h, opcode, args = q.popleft()
-                if h == log_idx:
+                if log_idx is None or h == log_idx:
                     ops.append((opcode, *args))
                     tids.append(tid)
+                    logs.append(h)
                 else:
                     keep.append((h, opcode, args))
             q.extend(keep)
         if not ops:
-            self._exec_round(log_idx)
+            self._exec_round()  # combine with nothing staged still helps
             return
-        self._append_and_replay_log(log_idx, rid, ops, tids)
+        self._finish_round(self._begin_round(ops, rid, tids, logs))
 
     def _fused_log_spec(self) -> LogSpec:
         """The single-log `LogSpec` the fused engine is built against —
@@ -433,180 +459,195 @@ class MultiLogReplicated(_FusedTier):
         return fn
 
     @_locked
-    def _try_fused_round_log(self, log_idx: int, rid: int, ops, tids,
-                             n: int, pos0: int, pad: int,
-                             opcodes, args, pending=None) -> bool:
-        """Route one per-log combiner pass through the fused engine
-        when the log is lock-step eligible (the NodeReplicated
-        `_try_fused_round` twin, minus fencing/WAL, which CNR does not
-        carry). With `pending` (the split-round path) the kernel is
-        launched here and the response readback deferred to
-        `_finish_round_log`."""
+    def _try_fused_round(self, groups: dict[int, list[int]], ops, rid: int,
+                         tids: list[int], pending: _PendingCnrRound,
+                         defer: bool) -> bool:
+        """Route the round through the fused engine, one launch per
+        touched log, when every touched log is lock-step eligible (the
+        NodeReplicated `_try_fused_round` twin, minus fencing/WAL, which
+        CNR does not carry); False runs the append+replay programs. With
+        `defer` (the split-round path) the launches are issued here and
+        their responses read back by `_finish_round`."""
         eng = self._fused_engine()
         if eng is None:
             return False
-        if not eng.supports(pad):
+        pads = {h: 1 << (len(idxs) - 1).bit_length()
+                for h, idxs in groups.items()}
+        lts, tails = jax.device_get((self.ml.ltails, self.ml.tail))
+        if (not all(eng.supports(p) for p in pads.values())
+                or any(self._inflight.get((r, h))
+                       for h in groups for r in range(self.n_replicas))
+                or not all((lts[h] == tails[h]).all() for h in groups)):
             self._m_fused_fallback.inc()
             return False
-        if any(self._inflight.get((r, log_idx))
-               for r in range(self.n_replicas)):
-            self._m_fused_fallback.inc()
-            return False
-        lts, tail = host_cursors(self.ml.ltails[log_idx],
-                                 self.ml.tail[log_idx])
-        if not (int(lts.min()) == tail == int(lts.max())):
-            self._m_fused_fallback.inc()
-            return False
-        t0 = time.perf_counter()
-        fn = self._fused_cnr_round(eng, pad)
-        extra = {"deferred": True} if pending is not None else {}
-        with span("fused-round", log=log_idx, rid=rid, n=n, pos0=pos0,
-                  window=pad, **extra) as sp:
-            self.ml, self.states, resps = fn(
-                self.ml, self.states, jnp.int32(log_idx), opcodes,
-                args, n,
-            )
-            if pending is None:
-                resps_np = np.asarray(resps)
-                sp.fence(self.ml, self.states)
-        dt = time.perf_counter() - t0
-        # the CNR path embeds the raw round_fn in its own program, so
-        # the engine's round() wrapper never runs — report through the
-        # same instrumentation hook (tier counter + kernel.* metrics +
-        # kernel-launch event; one contract, never two)
-        eng.note_round(pad, n, dt)
-        self._fused_rounds += 1
-        self._m_engine_fused.inc()
-        if pending is not None:
-            pending.fused_resps = resps
-            return True
-        for j, tid in enumerate(tids):
-            self._resps[(rid, tid)].append(int(resps_np[rid, j]))
-        self.last_round_tier = "pallas_fused"
-        self._tier_by_rid[rid] = "pallas_fused"
-        self._pos_by_rid[rid] = pos0
+        launched = []
+        for h in sorted(groups):
+            idxs = groups[h]
+            opcodes, args = self._encode([ops[i] for i in idxs], pads[h])
+            t0 = time.perf_counter()
+            fn = self._fused_cnr_round(eng, pads[h])
+            with span("fused-round", log=h, rid=rid, n=len(idxs),
+                      pos0=int(tails[h]), window=pads[h]) as sp:
+                self.ml, self.states, resps = fn(
+                    self.ml, self.states, jnp.int32(h), jnp.asarray(opcodes),
+                    jnp.asarray(args), len(idxs),
+                )
+                if not defer:
+                    resps = np.asarray(resps)
+                    sp.fence(self.ml, self.states)
+            # the CNR path embeds the raw round_fn in its own program, so
+            # the engine's round() wrapper never runs — report through the
+            # same instrumentation hook (tier counter + kernel.* metrics +
+            # kernel-launch event; one contract, never two)
+            eng.note_round(pads[h], len(idxs), time.perf_counter() - t0)
+            self._fused_rounds += 1
+            self._m_engine_fused.inc()
+            launched.append((h, idxs, resps))
+        pending.fused = launched
+        if not defer:
+            self._deliver_fused(pending, tids)
         return True
 
+    def _deliver_fused(self, pending: _PendingCnrRound,
+                       tids: list[int]) -> None:
+        rid = pending.rid
+        for h, idxs, resps in pending.fused:
+            resps = np.asarray(resps)
+            for j, i in enumerate(idxs):
+                self._deliver(rid, tids[i], i, int(resps[rid, j]))
+        pending.fused = None
+        pending.done = True
+        self._note_tier(rid, "pallas_fused", pending.pos0)
+
+    def _encode(self, ops: list[tuple], pad: int):
+        opcodes = np.full((pad,), NOOP, np.int32)
+        args = np.zeros((pad, self.spec.arg_width), np.int32)
+        for j, op in enumerate(ops):
+            opcodes[j] = op[0]
+            args[j, : len(op) - 1] = op[1:]
+        return opcodes, args
+
+    def _note_tier(self, rid: int, tier: str, pos0: int) -> None:
+        self.last_round_tier = tier
+        self._tier_by_rid[rid] = tier
+        self._pos_by_rid[rid] = pos0
+
+    def _deliver(self, rid: int, tid: int, index: int, resp: int) -> None:
+        if tid == BATCH_TID:
+            self._batch_out[rid][index] = resp
+        else:
+            self._resps[(rid, tid)].append(resp)
+
     @_locked
-    def _begin_round_log(self, log_idx: int, rid: int,
-                         ops: list[tuple], tids: list[int],
-                         batch: bool = False,
-                         defer: bool = False) -> _PendingRound:
-        """First half of the per-log combiner pass (the NodeReplicated
-        `_begin_round` twin): wait for ring space on this log, encode
-        + append, record each op's in-flight response destination.
-        `defer=True` leaves the replay-to-target (or the fused
-        launch's readback) for `_finish_round_log`. The lock is
-        reentrant: callers already hold it."""
+    def _begin_round(self, ops: list[tuple], rid: int, tids: list[int],
+                     logs: list[int] | None = None,
+                     defer: bool = False) -> _PendingCnrRound:
+        """First half of the combiner round (the NodeReplicated
+        `_begin_round` twin): route every op through the `LogMapper`,
+        wait for ring space on every touched log (helping GC), and append
+        every touched log's sub-batch with ONE program (`jit_cnr_append`)
+        — or, on the fused tier, launch each log's round. Records each
+        op's in-flight response destination; `tids[i]` is op i's thread
+        (BATCH_TID: the replica's batch, by index), `logs[i]` its log
+        where staging mapped it already. `defer` leaves the
+        fused launches' readback for `_finish_round`; the chain's replay
+        is always `_finish_round`'s. The lock is reentrant: callers
+        already hold it."""
         fault_hook("append", rid, self)
         n = len(ops)
-        self._combine_rounds[log_idx] += 1
+        groups: dict[int, list[int]] = {}
+        for i, op in enumerate(ops):
+            h = self._map(op) if logs is None else logs[i]
+            groups.setdefault(h, []).append(i)
+        max_batch = self.spec.capacity - self.spec.gc_slack
+        for h, idxs in groups.items():
+            if len(idxs) > max_batch:
+                raise LogTooSmallError(
+                    f"log {h}: sub-batch of {len(idxs)} exceeds "
+                    f"appendable capacity {max_batch}"
+                )
+        counts = np.zeros((self.nlogs,), np.int64)
+        for h, idxs in groups.items():
+            counts[h] = len(idxs)
+            self._combine_rounds[h] += 1
         self._m_combine.inc()
         self._m_batch.observe(n)
+        self._m_logs.observe(len(groups))
+        # ring space on every touched log, the cursors fetched once a try
         rounds = 0
-        while (
-            self.spec.capacity - self.spec.gc_slack
-            - int(np.asarray(self.ml.tail - self.ml.head)[log_idx])
-        ) < n:
-            self._exec_round(log_idx)
-            rounds = self._watchdog(rounds, log_idx, "append-gc")
-        pos0 = int(np.asarray(self.ml.tail)[log_idx])
-        pad = 1 << (max(n, 1) - 1).bit_length()
-        opcodes, args, _ = encode_ops(
-            ops, self.spec.arg_width, pad_to=pad
-        )
-        pending = _PendingRound(rid, list(tids), n, pos0, batch=batch,
-                                log_idx=log_idx)
-        if self._try_fused_round_log(log_idx, rid, ops, tids, n, pos0,
-                                     pad, opcodes, args,
-                                     pending if defer else None):
-            if pending.fused_resps is None:
-                pending.done = True  # ran eagerly end-to-end
+        while True:
+            tail, head = jax.device_get((self.ml.tail, self.ml.head))
+            short = np.flatnonzero(max_batch - (tail - head) < counts)
+            if not short.size:
+                break
+            self._exec_round()
+            rounds = self._watchdog(rounds, int(short[0]), "append-gc")
+        pending = _PendingCnrRound(rid, n)
+        pending.targets = [(h, int(tail[h] + counts[h])) for h in sorted(groups)]
+        pending.pos0 = int(tail[max(groups)])
+        if self._try_fused_round(groups, ops, rid, tids, pending, defer):
             return pending
-        extra = {"batch": True} if batch else {}
-        with span("append", log=log_idx, rid=rid, n=n, pos0=pos0,
-                  **extra) as sp:
-            self.ml = self._append_jit(
-                self.ml, log_idx, opcodes, args, jnp.int64(n)
-            )
+        pad = 1 << (int(counts.max()) - 1).bit_length()
+        opcodes = np.full((self.nlogs, pad), NOOP, np.int32)
+        args = np.zeros((self.nlogs, pad, self.spec.arg_width), np.int32)
+        for h, idxs in groups.items():
+            opcodes[h], args[h] = self._encode([ops[i] for i in idxs], pad)
+        with span("append", rid=rid, n=n, logs=len(groups)) as sp:
+            self.ml = self._append_jit(self.ml, opcodes, args, counts)
             sp.fence(self.ml)
-        infl = self._inflight.setdefault((rid, log_idx), deque())
-        for j, tid in enumerate(tids):
-            infl.append((pos0 + j, tid))
+        for h, idxs in groups.items():
+            infl = self._inflight.setdefault((rid, h), deque())
+            for j, i in enumerate(idxs):
+                infl.append((int(tail[h]) + j, tids[i], i))
         return pending
 
     @_locked
-    def _finish_round_log(self, pending: _PendingRound) -> None:
-        """Second half of the per-log combiner pass: replay this log
-        until replica `rid` has applied its own ops, or read back and
-        deliver the fused launch's responses."""
+    def _finish_round(self, pending: _PendingCnrRound) -> None:
+        """Second half of the combiner round: replay every log until
+        replica `rid` has applied its own ops on each touched log (one
+        replay program a pass, `jit_cnr_exec`), or read back and deliver
+        the fused launches' responses."""
         if pending.done:
             return
-        pending.done = True
-        rid, log_idx = pending.rid, pending.log_idx
-        if pending.fused_resps is not None:
-            resps_np = np.asarray(pending.fused_resps)
-            pending.fused_resps = None
-            for j, tid in enumerate(pending.tids):
-                self._resps[(rid, tid)].append(int(resps_np[rid, j]))
-            self.last_round_tier = "pallas_fused"
-            self._tier_by_rid[rid] = "pallas_fused"
-            self._pos_by_rid[rid] = pending.pos0
+        rid = pending.rid
+        if pending.fused is not None:
+            self._deliver_fused(pending, [BATCH_TID] * pending.n)
             return
-        target = pending.target
+        pending.done = True
         rounds = 0
-        with span("combine-replay", log=log_idx, rid=rid,
-                  target=target) as sp:
-            while int(np.asarray(self.ml.ltails)[log_idx, rid]) < target:
-                self._exec_round(log_idx)
-                rounds = self._watchdog(rounds, log_idx, "combine-replay")
+        with span("combine-replay", rid=rid, logs=len(pending.targets)) as sp:
+            while True:
+                lt = self._exec_round()
+                behind = [h for h, t in pending.targets if lt[h, rid] < t]
+                if not behind:
+                    break
+                rounds = self._watchdog(rounds, behind[0], "combine-replay")
             sp.fence(self.ml, self.states)
-        self.last_round_tier = "scan"
-        self._tier_by_rid[rid] = "scan"
-        self._pos_by_rid[rid] = pending.pos0
-
-    @_locked
-    def _append_and_replay_log(self, log_idx: int, rid: int,
-                               ops: list[tuple], tids: list[int],
-                               batch: bool = False) -> None:
-        """Shared per-log combiner-pass tail (`combine` and
-        `execute_mut_batch`'s sub-batches — one protocol, never two):
-        `_begin_round_log` + `_finish_round_log` back-to-back; the
-        split-round path (`begin_mut_batch`) runs the same halves
-        spread across the serve pipeline's stages. Lock-step-eligible
-        passes route through the fused pallas tier when selected
-        (`_try_fused_round_log`) — one kernel launch per sub-batch."""
-        self._finish_round_log(
-            self._begin_round_log(log_idx, rid, ops, tids, batch=batch)
-        )
+        self._note_tier(rid, self.engine, pending.pos0)
 
     @_locked
     def _drop_batch_inflight(self, rid: int) -> None:
         """Failed-batch hygiene (the NodeReplicated twin): drop every
         pending BATCH_TID delivery for this replica on every log and
-        clear the sink, so the next batch cannot inherit stale replies
-        (and a short sink cannot wedge every later batch on this
-        replica)."""
+        the batch's response list, so the next batch cannot inherit
+        stale replies. The appended ops stay in the logs and replay."""
         for key in [(rid, h) for h in range(self.nlogs)
                     if (rid, h) in self._inflight]:
             self._inflight[key] = deque(
-                (p, t) for p, t in self._inflight[key]
-                if t != BATCH_TID
+                e for e in self._inflight[key] if e[1] != BATCH_TID
             )
-        sink = self._resps.get((rid, BATCH_TID))
-        if sink is not None:
-            sink.clear()
+        self._batch_out.pop(rid, None)
 
-    @_locked
+    @_locked_phase("round", _batch_fields)
     def begin_mut_batch(self, ops: list[tuple],
-                        rid: int = 0) -> "_PendingCnrBatch":
+                        rid: int = 0) -> _PendingCnrRound:
         """Split-round batch entry, first half (the
-        `NodeReplicated.begin_mut_batch` twin): route each op through
-        the `LogMapper`, then append + journal every per-log sub-batch
-        in log order, deferring each log's replay-to-target to
-        `finish_mut_batch`. At most ONE begun-but-unfinished batch per
-        replica (`RuntimeError` otherwise). NOT atomic across logs —
-        the same per-log contract as `execute_mut_batch`."""
+        `NodeReplicated.begin_mut_batch` twin): route, wait for ring
+        space and append every touched log's sub-batch, deferring the
+        replay to `finish_mut_batch`. At most ONE begun-but-unfinished
+        batch per replica (`RuntimeError` otherwise). A raise out of
+        `begin` before the append leaves no op appended; after it, every
+        op is appended, on every log."""
         if not 0 <= rid < self.n_replicas:
             raise ValueError(f"replica {rid} out of range")
         if self._pending_batch.get(rid) is not None:
@@ -616,143 +657,75 @@ class MultiLogReplicated(_FusedTier):
                 f"(at most one split round per replica)"
             )
         n = len(ops)
-        sink = self._resps.get((rid, BATCH_TID))
-        if sink is None:
-            sink = deque()
-            self._resps[(rid, BATCH_TID)] = sink
-        groups: dict[int, list[int]] = {}
-        for i, op in enumerate(ops):
-            groups.setdefault(self._map(op), []).append(i)
-        max_batch = self.spec.capacity - self.spec.gc_slack
-        for h, idxs in groups.items():
-            if len(idxs) > max_batch:
-                raise LogTooSmallError(
-                    f"log {h}: sub-batch of {len(idxs)} exceeds "
-                    f"appendable capacity {max_batch}"
-                )
-        pend = _PendingCnrBatch(rid, n)
-        try:
-            for h in sorted(groups):
-                idxs = groups[h]
-                sub = self._begin_round_log(
-                    h, rid, [ops[i] for i in idxs],
-                    [BATCH_TID] * len(idxs), batch=True, defer=True,
-                )
-                pend.subs.append((h, idxs, sub))
-        except BaseException:
-            self._drop_batch_inflight(rid)
-            raise
-        self._pending_batch[rid] = pend
-        return pend
+        out: list = [None] * n
+        if n == 0:
+            pending = _PendingCnrRound(rid, 0)
+            pending.done = True
+        else:
+            self._batch_out[rid] = out
+            try:
+                pending = self._begin_round(list(ops), rid, [BATCH_TID] * n,
+                                            defer=True)
+            except BaseException:
+                self._drop_batch_inflight(rid)
+                raise
+        pending.out = out
+        self._pending_batch[rid] = pending
+        return pending
 
-    @_locked
-    def finish_mut_batch(self, pend: "_PendingCnrBatch") -> list:
-        """Split-round batch entry, second half: replay every per-log
-        sub-round to its target (in the same log order `begin`
-        appended), scatter responses back to submission indices,
-        release the replica's in-flight slot."""
-        rid = pend.rid
-        if self._pending_batch.get(rid) is not pend:
+    @_locked_phase("round", _pending_fields)
+    def finish_mut_batch(self, pending: _PendingCnrRound) -> list:
+        """Split-round batch entry, second half: replay to every touched
+        log's target (or read back the fused launches), collect the
+        responses in submission order, release the replica's in-flight
+        slot."""
+        rid = pending.rid
+        if self._pending_batch.get(rid) is not pending:
             raise RuntimeError(
                 f"pending batch for replica {rid} is not this "
                 f"replica's in-flight batch (already finished?)"
             )
-        sink = self._resps[(rid, BATCH_TID)]
-        out: list = [None] * pend.n
         try:
-            for h, idxs, sub in pend.subs:
-                self._finish_round_log(sub)
-                assert len(sink) == len(idxs), (len(sink), len(idxs))
-                for i in idxs:
-                    out[i] = sink.popleft()
-            return out
+            self._finish_round(pending)
+            assert None not in pending.out, pending.out
+            return pending.out
         except BaseException:
             self._drop_batch_inflight(rid)
             raise
         finally:
             self._pending_batch.pop(rid, None)
+            if self._batch_out.get(rid) is pending.out:
+                del self._batch_out[rid]
 
     @_locked
-    def abort_mut_batch(self, pend: "_PendingCnrBatch") -> None:
+    def abort_mut_batch(self, pending: _PendingCnrRound) -> None:
         """Abandon a begun-but-unfinished split batch (the
-        `NodeReplicated.abort_mut_batch` twin): every appended sub-
-        batch WILL replay; only response delivery drops. Idempotent."""
-        rid = pend.rid
-        if self._pending_batch.get(rid) is not pend:
+        `NodeReplicated.abort_mut_batch` twin): every appended op WILL
+        replay; only response delivery drops. Idempotent."""
+        rid = pending.rid
+        if self._pending_batch.get(rid) is not pending:
             return
         self._pending_batch.pop(rid, None)
-        for _, _, sub in pend.subs:
-            sub.done = True
-            sub.fused_resps = None
+        pending.done = True
+        pending.fused = None
         self._drop_batch_inflight(rid)
 
-    @_locked
+    @_locked_phase("round", _batch_fields)
     def execute_mut_batch(self, ops: list[tuple],
                           rid: int = 0) -> list:
-        """Execute a caller-assembled batch as one combiner pass PER
-        MAPPED LOG and return responses in submission order — the CNR
-        twin of `NodeReplicated.execute_mut_batch` (the serve
-        frontend's serial entry point).
+        """Execute a caller-assembled batch as ONE combiner round and
+        return responses in submission order — the CNR twin of
+        `NodeReplicated.execute_mut_batch` (the serve frontend's serial
+        entry point), and `begin_mut_batch` + `finish_mut_batch`
+        back-to-back under one lock hold.
 
         Each op routes through the `LogMapper` exactly as `execute_mut`
-        would (`cnr/src/replica.rs:435`); the batch then splits into
-        per-log sub-batches that append and replay one log at a time,
-        in log order (each pass is `_begin_round_log` +
-        `_finish_round_log` back-to-back, the same halves the
-        split-round path runs). A failure during log `h`'s pass
-        therefore leaves later logs' sub-batches UNappended — the
-        historical serial contract — whereas the split path
-        (`begin_mut_batch`) appends every sub-batch up front so the
-        whole batch shares one post-append failure class. Responses
-        come back through a dedicated deque sink keyed
-        `(rid, BATCH_TID)` and are scattered back to the callers'
-        submission indices, so interleaving with per-thread
-        `execute_mut` traffic on the same replica stays ordered.
-        """
-        if not 0 <= rid < self.n_replicas:
-            raise ValueError(f"replica {rid} out of range")
-        if self._pending_batch.get(rid) is not None:
-            # the NodeReplicated guard (there via begin_mut_batch): a
-            # serial batch interleaved with a begun split batch would
-            # deliver the split batch's appended entries into the
-            # shared BATCH_TID sink and scatter wrong responses
-            raise RuntimeError(
-                f"replica {rid} already has a batch in flight; "
-                f"finish_mut_batch it before executing another"
-            )
-        n = len(ops)
-        if n == 0:
-            return []
-        sink = self._resps.get((rid, BATCH_TID))
-        if sink is None:
-            sink = deque()
-            self._resps[(rid, BATCH_TID)] = sink
-        groups: dict[int, list[int]] = {}
-        for i, op in enumerate(ops):
-            groups.setdefault(self._map(op), []).append(i)
-        max_batch = self.spec.capacity - self.spec.gc_slack
-        for h, idxs in groups.items():
-            if len(idxs) > max_batch:
-                raise LogTooSmallError(
-                    f"log {h}: sub-batch of {len(idxs)} exceeds "
-                    f"appendable capacity {max_batch}"
-                )
-        out: list = [None] * n
-        try:
-            for h in sorted(groups):
-                idxs = groups[h]
-                m = len(idxs)
-                self._append_and_replay_log(
-                    h, rid, [ops[i] for i in idxs],
-                    [BATCH_TID] * m, batch=True,
-                )
-                assert len(sink) == m, (len(sink), m)
-                for i in idxs:
-                    out[i] = sink.popleft()
-            return out
-        except BaseException:
-            self._drop_batch_inflight(rid)
-            raise
+        would (`cnr/src/replica.rs:435`); one program appends every
+        touched log's sub-batch, and one program a pass replays every
+        log until this replica has applied its ops. Interleaving with
+        per-thread `execute_mut` traffic on the same replica stays
+        ordered: the batch's responses go to its own list, by index."""
+        return self.finish_mut_batch(self.begin_mut_batch(ops, rid))
 
     @_locked
     def sync(self, rid: int | None = None) -> None:
@@ -763,21 +736,18 @@ class MultiLogReplicated(_FusedTier):
     @_locked
     def sync_log(self, rid: int | None, log_idx: int) -> None:
         """Targeted single-log sync (`sync_log`,
-        `cnr/src/replica.rs:579-597`). The harness wires the GC callback
-        to this, answering starvation reports (`benches/mkbench.rs:
-        763-772`)."""
+        `cnr/src/replica.rs:579-597`): replay until log `log_idx` is
+        applied to its tail on replica `rid` (every replica for None);
+        each replay round replays every log. The harness wires the GC
+        callback to this, answering starvation reports
+        (`benches/mkbench.rs:763-772`)."""
         rounds = 0
+        lt, tail = jax.device_get((self.ml.ltails, self.ml.tail))
         while True:
-            lt = np.asarray(self.ml.ltails)[log_idx]
-            tail = int(np.asarray(self.ml.tail)[log_idx])
-            done = (
-                all(int(x) >= tail for x in lt)
-                if rid is None
-                else int(lt[rid]) >= tail
-            )
-            if done:
+            row = lt[log_idx] if rid is None else lt[log_idx, rid]
+            if (row >= tail[log_idx]).all():
                 return
-            self._exec_round(log_idx)
+            lt = self._exec_round()
             rounds = self._watchdog(rounds, log_idx, "sync")
 
     @_locked
@@ -865,41 +835,38 @@ class MultiLogReplicated(_FusedTier):
     # ------------------------------------------------------------ internals
 
     @_locked
-    def _exec_round(self, log_idx: int) -> None:
+    def _exec_round(self) -> np.ndarray:
+        """One replay pass of every log into every replica (one program,
+        `jit_cnr_exec`), plus response delivery. Returns the host copy of
+        the replicas' cursors after it, `ltails[log, replica]`."""
         fault_hook("replay", -1, self)
-        # one fused cursor readback per round (see the
-        # NodeReplicated._exec_round note on D2H round trips)
-        lt_before, tail = host_cursors(self.ml.ltails[log_idx],
-                                       self.ml.tail[log_idx])
-        # idle short-circuit (the NodeReplicated._exec_round twin): all
-        # replicas at this log's tail → nothing to replay, skip the
+        # one cursor fetch (ltails + tail) per round
+        lt_before, tail = jax.device_get((self.ml.ltails, self.ml.tail))
+        # idle short-circuit (the NodeReplicated._exec_round twin): every
+        # replica at every log's tail → nothing to replay, skip the
         # device round; every caller loops on a cursor condition already
         # satisfied, so skipping cannot livelock
-        if int(lt_before.min()) >= tail and int(lt_before.max()) <= tail:
+        if (lt_before == tail[:, None]).all():
             self._idle_rounds += 1
             self._m_idle.inc()
-            return
+            return lt_before
         self._exec_rounds += 1
         self._m_rounds.inc()
         self.ml, self.states, resps = self._exec_jit(
-            self.ml, self.states, log_idx=log_idx, window=self.exec_window
+            self.ml, self.states, window=self.exec_window
         )
-        lt_after = np.asarray(self.ml.ltails)[log_idx]
-        resps_np = np.asarray(resps)
+        lt_after, resps_np = jax.device_get((self.ml.ltails, resps))
         if self.mesh is not None:
             self._m_mesh_round.inc()
-            self._m_mesh_sync_bytes.inc(resps_np.nbytes
-                                        + lt_before.nbytes + 8
-                                        + lt_after.nbytes)
-        for r in range(self.n_replicas):
-            q = self._inflight.get((r, log_idx))
-            if not q:
-                continue
-            while q and q[0][0] < int(lt_after[r]):
-                pos, tid = q.popleft()
-                self._resps[(r, tid)].append(
-                    int(resps_np[r, pos - int(lt_before[r])])
-                )
+            self._m_mesh_sync_bytes.inc(resps_np.nbytes + lt_before.nbytes
+                                        + tail.nbytes + lt_after.nbytes)
+        for (r, h), q in self._inflight.items():
+            done = int(lt_after[h, r])
+            while q and q[0][0] < done:
+                pos, tid, i = q.popleft()
+                self._deliver(r, tid, i,
+                              int(resps_np[h, r, pos - int(lt_before[h, r])]))
+        return lt_after
 
     def _watchdog(self, rounds: int, log_idx: int, where: str) -> int:
         rounds += 1

@@ -196,6 +196,79 @@ def _exec_one_log_combined(spec, d, opcodes_ring, args_ring, tail, state,
     return state, resps, jnp.minimum(ltail + window, tail)
 
 
+def multilog_exec_window(
+    spec: MultiLogSpec,
+    d: Dispatch,
+    ml: MultiLogState,
+    states: PyTree,
+    window: int,
+):
+    """Replay up to `window` pending entries of EVERY log into every
+    replica, as one program: the multi-log combiner round's replay
+    (`MultiLogReplicated`), with the log set as data, not as a static
+    log index.
+
+    The union window (the `core/log._catchup_union_plan` idea, per log):
+    log l's `window` positions from its laggard's cursor `m_l`, past its
+    tail read as NOOP; the L windows lie end to end, log after log, and
+    replica r applies the positions of each log from its own cursor on,
+    so replicas that stand at different positions on any log are
+    replayed exactly, and each to `max(ltail, min(m_l + window, tail))`.
+    Ops on different logs commute (the LogMapper contract,
+    `cnr/src/lib.rs:123-137`), so log order is as good as any
+    interleaving; each log's own order is kept. The window is applied by
+    the model's `window_apply_stacked` where it has one (the hashmap's
+    writes each touched slot's column of replicas), else each replica's
+    masked window by a vmapped `window_apply`, else by a scan of
+    `apply_write`.
+
+    Returns `(ml, states, resps int32[L, R, window])`; `resps[l, r, i]`
+    answers log l's entry at position `old ltails[l, r] + i`.
+    """
+    L, W, A = spec.nlogs, window, spec.arg_width
+    start = jnp.min(ml.ltails, axis=1)  # [L]
+    pos = start[:, None] + jnp.arange(W, dtype=jnp.int64)  # [L, W]
+    idx = (pos & spec.mask).astype(jnp.int32)
+    logs = jnp.broadcast_to(jnp.arange(L)[:, None], (L, W))
+    opcodes = jnp.where(pos < ml.tail[:, None], ml.opcodes[logs, idx], NOOP)
+    args = ml.args[logs, idx]  # [L, W, A]
+    # [L, W, R]: the positions replica r applies, from its own cursor
+    applies = ((pos[:, :, None] >= ml.ltails[:, None, :])
+               & (opcodes != NOOP)[:, :, None])
+    opcodes = opcodes.reshape(L * W)
+    args = args.reshape(L * W, A)
+    applies = applies.reshape(L * W, -1)
+    if d.window_apply_stacked is not None:
+        states, resps = d.window_apply_stacked(states, opcodes, args,
+                                               applies)
+    else:
+        def one(state, mask):
+            opc = jnp.where(mask, opcodes, NOOP)
+            if d.window_apply is not None:
+                return d.window_apply(state, opc, args)
+            return lax.scan(lambda s, x: apply_write(d, s, x[0], x[1]),
+                            state, (opc, args))
+
+        states, resps = jax.vmap(one, in_axes=(0, 1), out_axes=(0, 1))(
+            states, applies)
+    # replica r's answers from its own cursor: offset ltails - m_l
+    resps = resps.reshape(L, W, -1)
+    off = (ml.ltails - start[:, None])[:, :, None] + jnp.arange(
+        W, dtype=jnp.int64)  # [L, R, W]
+    resps = jnp.take_along_axis(
+        jnp.swapaxes(resps, 1, 2), jnp.minimum(off, W - 1).astype(jnp.int32),
+        axis=2)
+    resps = jnp.where(off < W, resps, 0)
+    end = jnp.minimum(start + W, ml.tail)
+    ltails = jnp.maximum(ml.ltails, end[:, None])
+    ml = ml._replace(
+        ltails=ltails,
+        ctail=jnp.maximum(ml.ctail, jnp.max(ltails, axis=1)),
+        head=jnp.min(ltails, axis=1),
+    )
+    return ml, states, resps
+
+
 def multilog_exec_all(
     spec: MultiLogSpec,
     d: Dispatch,

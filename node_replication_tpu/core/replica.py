@@ -364,6 +364,199 @@ def states_equal(states) -> bool:
     )
 
 
+class _ReadCombining:
+    """Read combining, the one read path of `NodeReplicated` and
+    `MultiLogReplicated` (`core/cnr.py`) for models with
+    `Dispatch.read_stacked`: concurrent readers post their reads, and
+    one of them answers every posted read under one hold of the
+    combiner lock, with one batched `jit_read_stacked` program per
+    READ_BATCH_MAX reads and one readback.
+
+    The host provides `_lock`, `states`, `dispatch`, `spec.arg_width`,
+    `_read_jit` (the single-read program), `_execute_one(op, token)` (a
+    read alone, under its own hold), `_read_refused(rid)` (the error a
+    read of replica `rid` fails with, or None) and `_sync_reads(live)`
+    (replay, under the lock, what each read must see: NR syncs a lagging
+    replica to the completed tail, CNR a read's mapped log on its
+    replica to that log's completed tail, `cnr/src/replica.rs:599-617`)."""
+
+    def _init_read_combining(self) -> None:
+        # reads posted for the next combined hold, and whether a reader
+        # is combining; guarded by `_read_mu`, never by the combiner
+        # lock, so posting never waits on a round
+        self._read_mu = make_lock("_ReadCombining._read_mu")
+        self._read_posted: deque[_ReadSlot] = deque()
+        self._read_combining = False
+        self._read_batch = None
+        self._m_read_batch = get_registry().histogram(
+            "nr.read.batch_size", buckets=COUNT_BUCKETS)
+
+    def execute(self, op: tuple, token: ReplicaToken):
+        """Read path (`Replica::execute` → `read_only`,
+        `nr/src/replica.rs:404-410`, `483-497`): wait until this replica has
+        replayed what the read must see (`_sync_reads`: NR's completed
+        tail, a CNR read's mapped log's), helping replay while waiting,
+        then dispatch locally against replica state. Answers an int
+        where the read program answers one word (the hashmap's get),
+        else an `np.ndarray` of int32 words (the record store's wide
+        read).
+
+        Reads of a model with `Dispatch.read_stacked` are combined, as
+        writes are: a reader posts its read, and if no reader is
+        combining, it answers every posted read under one hold of the
+        combiner lock (`_lead_reads`); otherwise it waits for its
+        answer, or for the role. A caller that holds the lock already
+        reads alone, as does every read of a model without
+        `read_stacked` (`_read_one` would copy a whole replica per
+        batched read)."""
+        if self.dispatch.read_stacked is None or self._lock._is_owned():
+            return self._execute_one(op, token)
+        slot = _ReadSlot(token.rid, op)
+        with self._read_mu:
+            self._read_posted.append(slot)
+            slot.lead = not self._read_combining
+            self._read_combining = True
+        if not slot.lead:
+            slot.ready.wait()
+        if slot.lead:
+            self._lead_reads(slot)
+        if slot.error is not None:
+            raise slot.error
+        return slot.value
+
+    def _lead_reads(self, slot: _ReadSlot) -> None:
+        """The read combiner's turn: answer the posted reads under one
+        hold (`_combine_reads`); then, with the lock released, hand the
+        role to the oldest read posted meanwhile (or clear it) and wake
+        the readers. An answered reader never takes the lock. Whatever
+        is raised reaches every read of the batch, and the role is
+        handed on or cleared, so no reader waits forever."""
+        batch: list[_ReadSlot] = []
+        try:
+            self._combine_reads(slot, batch)
+        except BaseException as e:
+            for s in batch:
+                if s is not slot and s.error is None:
+                    s.error = e
+            raise
+        finally:
+            with self._read_mu:
+                if not batch:  # raised before its drain: withdraw it
+                    self._read_posted.remove(slot)
+                posted = self._read_posted
+                nxt = posted[0] if posted else None
+                self._read_combining = nxt is not None
+                if nxt is not None:
+                    nxt.lead = True
+            if nxt is not None:
+                nxt.ready.set()
+            for s in batch:
+                s.ready.set()
+
+    @_locked_phase("read", _slot_fields)
+    def _combine_reads(self, slot: _ReadSlot, batch: list) -> None:
+        """One hold for the posted reads, `slot` (the oldest) first:
+        drain up to READ_DRAIN_MAX of them into `batch`, sync what they
+        must see (`_sync_reads`, its cursors fetched once), and answer
+        the reads from one batched read program per READ_BATCH_MAX of
+        them, with one readback. A read its host refuses
+        (`_read_refused`: a fenced replica's), or one its `read-sync`
+        fault hook raises out of, fails alone.
+
+        Linearizable as a lone read is: every read of the batch was
+        posted before the completed tails were fetched, an acknowledged
+        write advanced its log's completed tail before its ack, and all
+        the reads see the replicas' states at one instant under the
+        lock, inside each read's interval."""
+        with self._read_mu:
+            posted = self._read_posted
+            batch.extend(posted.popleft()
+                         for _ in range(min(len(posted), READ_DRAIN_MAX)))
+        live = []
+        for s in batch:
+            s.error = self._read_refused(s.rid)
+            if s.error is not None:
+                continue
+            try:
+                fault_hook("read-sync", s.rid, self)
+            except Exception as e:
+                s.error = e
+                continue
+            live.append(s)
+        if not live:
+            return
+        self._sync_reads(live)
+        d = self.dispatch
+        if d.host_counters is not None and get_registry().enabled:
+            d.host_counters("read", [s.op for s in live])
+        run = self._read_batch_program()
+        chunks = [live[i:i + READ_BATCH_MAX]
+                  for i in range(0, len(live), READ_BATCH_MAX)]
+        outs = []
+        for chunk in chunks:
+            outs.append(run(self.states, *self._pack_reads(chunk)))
+            self._m_read_batch.observe(len(chunk))
+        for chunk, out in zip(chunks, jax.device_get(outs)):
+            out = out[: len(chunk)]
+            for s, v in zip(chunk, out.tolist() if out.ndim == 1 else out):
+                s.value = v
+
+    def _pack_reads(self, chunk: list[_ReadSlot]):
+        """Host arrays of a chunk's rids, opcodes and args, padded to
+        the next size of READ_BUCKETS with NOOP reads (opcode 0) of
+        replica 0, whose answers are dropped."""
+        n = len(chunk)
+        b = next(b for b in READ_BUCKETS if b >= n)
+        rids = np.zeros((b,), np.int32)
+        opcodes = np.zeros((b,), np.int32)
+        args = np.zeros((b, self.spec.arg_width), np.int32)
+        rids[:n] = [s.rid for s in chunk]
+        opcodes[:n] = [s.op[0] for s in chunk]
+        for j, s in enumerate(chunk):
+            args[j, : len(s.op) - 1] = s.op[1:]
+        return rids, opcodes, args
+
+    @_locked
+    def _read_batch_program(self):
+        """The batched read program: `_read_jit` vmapped over the reads
+        (the stacked states shared, one rid, opcode and args a read),
+        run as `jit_read_stacked`. Built from whatever `_read_jit` is,
+        so a batch answers what a lone read does; every size of
+        READ_BUCKETS compiles here, with the first read, so that none
+        compiles later."""
+        read = self._read_jit
+        if self._read_batch is None or self._read_batch[0] is not read:
+            run = jax.jit(_named(jax.vmap(read, in_axes=(None, 0, 0, 0)),
+                                 "read_stacked"))
+            for b in READ_BUCKETS:
+                z = np.zeros((b,), np.int32)
+                run(self.states, z, z,
+                    np.zeros((b, self.spec.arg_width), np.int32))
+            self._read_batch = (read, run)
+        return self._read_batch[1]
+
+    def _dispatch_read(self, rid: int, op: tuple) -> int | np.ndarray:
+        """Shared read-dispatch tail: pack args, run the read jit
+        against replica `rid`'s current state. A lone read (synced) and
+        NR's `execute_stale` (brownout) must never diverge on this step.
+        A 0-d answer comes back as an int, a wider one as the array of
+        its words. Caller holds the combiner lock and has fence-checked."""
+        d = self.dispatch
+        if d.host_counters is not None and get_registry().enabled:
+            d.host_counters("read", [op])
+        args = np.zeros((self.spec.arg_width,), np.int32)
+        args[: len(op) - 1] = op[1:]
+        out = np.asarray(
+            self._read_jit(
+                self.states,
+                jnp.int32(rid),
+                jnp.int32(op[0]),
+                jnp.asarray(args),
+            )
+        )
+        return int(out) if out.ndim == 0 else out
+
+
 class _FusedTier:
     """Fused-pallas-tier plumbing shared by `NodeReplicated` and
     `MultiLogReplicated` (`core/cnr.py`): the tier is ON only when the
@@ -486,7 +679,7 @@ class _FusedTier:
         return self._pos_by_rid.get(rid)
 
 
-class NodeReplicated(_FusedTier):
+class NodeReplicated(_ReadCombining, _FusedTier):
     """N replicas of one `Dispatch` data structure behind a shared log.
 
     Mirrors the user-facing surface of `Replica` + `Log` wiring from the
@@ -536,12 +729,7 @@ class NodeReplicated(_FusedTier):
         # Combiner lock (see `_locked`): guards log/states/cursor and
         # context bookkeeping against concurrent OS-thread callers.
         self._lock = make_rlock("NodeReplicated._lock")
-        # Read combining (`execute`): reads posted for the next combined
-        # hold, and whether a reader is combining; guarded by `_read_mu`,
-        # never by the combiner lock, so posting never waits on a round.
-        self._read_mu = make_lock("NodeReplicated._read_mu")
-        self._read_posted: deque[_ReadSlot] = deque()
-        self._read_combining = False
+        self._init_read_combining()
         self._contexts: dict[tuple[int, int], Context] = {}
         self._threads_per_replica = [0] * n_replicas
         # Appended-but-unanswered ops per replica: deque[(logical_pos, tid)].
@@ -577,8 +765,6 @@ class NodeReplicated(_FusedTier):
         self._m_stalls = reg.counter("nr.watchdog.stalls")
         self._m_lag = reg.histogram("nr.replica.lag",
                                     buckets=COUNT_BUCKETS)
-        self._m_read_batch = reg.histogram("nr.read.batch_size",
-                                           buckets=COUNT_BUCKETS)
 
         # Replay engine for every cursor catch-up loop (sync, read-sync,
         # combine-replay, recovery): 'combined' routes through
@@ -1177,38 +1363,6 @@ class NodeReplicated(_FusedTier):
             r = ctx.res()
         return out
 
-    def execute(self, op: tuple, token: ReplicaToken):
-        """Read path (`Replica::execute` → `read_only`,
-        `nr/src/replica.rs:404-410`, `483-497`): wait until this replica has
-        replayed up to the completed tail (helping replay while waiting),
-        then dispatch locally against replica state. Answers an int
-        where the read program answers one word (the hashmap's get),
-        else an `np.ndarray` of int32 words (the record store's wide
-        read).
-
-        Reads of a model with `Dispatch.read_stacked` are combined, as
-        writes are: a reader posts its read, and if no reader is
-        combining, it answers every posted read under one hold of the
-        combiner lock (`_lead_reads`); otherwise it waits for its
-        answer, or for the role. A caller that holds the lock already
-        reads alone, as does every read of a model without
-        `read_stacked` (`_read_one` would copy a whole replica per
-        batched read)."""
-        if self.dispatch.read_stacked is None or self._lock._is_owned():
-            return self._execute_one(op, token)
-        slot = _ReadSlot(token.rid, op)
-        with self._read_mu:
-            self._read_posted.append(slot)
-            slot.lead = not self._read_combining
-            self._read_combining = True
-        if not slot.lead:
-            slot.ready.wait()
-        if slot.lead:
-            self._lead_reads(slot)
-        if slot.error is not None:
-            raise slot.error
-        return slot.value
-
     @_locked_phase("read", _read_fields)
     def _execute_one(self, op: tuple, token: ReplicaToken):
         """`execute` of one read alone, under its own hold."""
@@ -1223,67 +1377,15 @@ class NodeReplicated(_FusedTier):
             rounds = self._watchdog(rounds, "read-sync")
         return self._dispatch_read(rid, op)
 
-    def _lead_reads(self, slot: _ReadSlot) -> None:
-        """The read combiner's turn: answer the posted reads under one
-        hold (`_combine_reads`); then, with the lock released, hand the
-        role to the oldest read posted meanwhile (or clear it) and wake
-        the readers. An answered reader never takes the lock. Whatever
-        is raised reaches every read of the batch, and the role is
-        handed on or cleared, so no reader waits forever."""
-        batch: list[_ReadSlot] = []
-        try:
-            self._combine_reads(slot, batch)
-        except BaseException as e:
-            for s in batch:
-                if s is not slot and s.error is None:
-                    s.error = e
-            raise
-        finally:
-            with self._read_mu:
-                if not batch:  # raised before its drain: withdraw it
-                    self._read_posted.remove(slot)
-                posted = self._read_posted
-                nxt = posted[0] if posted else None
-                self._read_combining = nxt is not None
-                if nxt is not None:
-                    nxt.lead = True
-            if nxt is not None:
-                nxt.ready.set()
-            for s in batch:
-                s.ready.set()
+    def _read_refused(self, rid: int) -> BaseException | None:
+        """The read combiner's admission of a read of replica `rid`: a
+        fenced replica's read fails alone."""
+        return ReplicaFencedError(rid) if self._is_fenced(rid) else None
 
-    @_locked_phase("read", _slot_fields)
-    def _combine_reads(self, slot: _ReadSlot, batch: list) -> None:
-        """One hold for the posted reads, `slot` (the oldest) first:
-        drain up to READ_DRAIN_MAX of them into `batch`, fetch the
-        cursors once, replay each lagging replica among them to the
-        completed tail, and answer the reads from one batched read
-        program per READ_BATCH_MAX of them, with one readback. A fenced
-        replica's read, or one its `read-sync` fault hook raises out
-        of, fails alone.
-
-        Linearizable as a lone read is: every read of the batch was
-        posted before `ctail` was fetched, an acknowledged write
-        advanced `ctail` before its ack, and all the reads see the
-        replicas' states at one instant under the lock, inside each
-        read's interval."""
-        with self._read_mu:
-            posted = self._read_posted
-            batch.extend(posted.popleft()
-                         for _ in range(min(len(posted), READ_DRAIN_MAX)))
-        live = []
-        for s in batch:
-            if self._is_fenced(s.rid):
-                s.error = ReplicaFencedError(s.rid)
-                continue
-            try:
-                fault_hook("read-sync", s.rid, self)
-            except Exception as e:
-                s.error = e
-                continue
-            live.append(s)
-        if not live:
-            return
+    def _sync_reads(self, live: list[_ReadSlot]) -> None:
+        """The read combiner's sync (`nr/src/replica.rs:404-410`): with
+        the cursors fetched once, replay each lagging replica among the
+        reads to the completed tail."""
         ltails, ctail = host_cursors(self.log.ltails, self.log.ctail)
         for rid in sorted({s.rid for s in live}):
             rounds = 0
@@ -1291,75 +1393,6 @@ class NodeReplicated(_FusedTier):
                 self._exec_round()
                 rounds = self._watchdog(rounds, "read-sync")
                 ltails = np.asarray(self.log.ltails)
-        d = self.dispatch
-        if d.host_counters is not None and get_registry().enabled:
-            d.host_counters("read", [s.op for s in live])
-        run = self._read_batch_program()
-        chunks = [live[i:i + READ_BATCH_MAX]
-                  for i in range(0, len(live), READ_BATCH_MAX)]
-        outs = []
-        for chunk in chunks:
-            outs.append(run(self.states, *self._pack_reads(chunk)))
-            self._m_read_batch.observe(len(chunk))
-        for chunk, out in zip(chunks, jax.device_get(outs)):
-            out = out[: len(chunk)]
-            for s, v in zip(chunk, out.tolist() if out.ndim == 1 else out):
-                s.value = v
-
-    def _pack_reads(self, chunk: list[_ReadSlot]):
-        """Host arrays of a chunk's rids, opcodes and args, padded to
-        the next size of READ_BUCKETS with NOOP reads (opcode 0) of
-        replica 0, whose answers are dropped."""
-        n = len(chunk)
-        b = next(b for b in READ_BUCKETS if b >= n)
-        rids = np.zeros((b,), np.int32)
-        opcodes = np.zeros((b,), np.int32)
-        args = np.zeros((b, self.spec.arg_width), np.int32)
-        rids[:n] = [s.rid for s in chunk]
-        opcodes[:n] = [s.op[0] for s in chunk]
-        for j, s in enumerate(chunk):
-            args[j, : len(s.op) - 1] = s.op[1:]
-        return rids, opcodes, args
-
-    @_locked
-    def _read_batch_program(self):
-        """The batched read program: `_read_jit` vmapped over the reads
-        (the stacked states shared, one rid, opcode and args a read),
-        run as `jit_read_stacked`. Built from whatever `_read_jit` is,
-        so a batch answers what a lone read does; every size of
-        READ_BUCKETS compiles here, with the first read, so that none
-        compiles later."""
-        read = self._read_jit
-        if self._read_batch is None or self._read_batch[0] is not read:
-            run = jax.jit(_named(jax.vmap(read, in_axes=(None, 0, 0, 0)),
-                                 "read_stacked"))
-            for b in READ_BUCKETS:
-                z = np.zeros((b,), np.int32)
-                run(self.states, z, z,
-                    np.zeros((b, self.spec.arg_width), np.int32))
-            self._read_batch = (read, run)
-        return self._read_batch[1]
-
-    def _dispatch_read(self, rid: int, op: tuple) -> int | np.ndarray:
-        """Shared read-dispatch tail: pack args, run the read jit
-        against replica `rid`'s current state. `execute` (synced) and
-        `execute_stale` (brownout) must never diverge on this step.
-        A 0-d answer comes back as an int, a wider one as the array of
-        its words. Caller holds the combiner lock and has fence-checked."""
-        d = self.dispatch
-        if d.host_counters is not None and get_registry().enabled:
-            d.host_counters("read", [op])
-        args = np.zeros((self.spec.arg_width,), np.int32)
-        args[: len(op) - 1] = op[1:]
-        out = np.asarray(
-            self._read_jit(
-                self.states,
-                jnp.int32(rid),
-                jnp.int32(op[0]),
-                jnp.asarray(args),
-            )
-        )
-        return int(out) if out.ndim == 0 else out
 
     @_locked
     def read_lag(self, rid: int) -> int:

@@ -13,6 +13,8 @@ if the key was present else 0).
 Read opcodes: HM_GET=1 (args k → resp value, or -1 when absent — the
 encoding of the reference's `Option<u64>` response). `NodeReplicated`
 answers a get through `read_stacked`, one slot of the stacked replicas.
+`hashmap_log_mapper` is the key, the LogMapper `MultiLogReplicated` routes
+the hashmap's ops by; its replay runs `window_apply_stacked`.
 Keys hash onto the dense table with `k % K` (uniform bench keys are already
 dense; the modulus mirrors a hash).
 """
@@ -20,6 +22,7 @@ dense; the modulus mirrors a hash).
 from __future__ import annotations
 
 import jax.numpy as jnp
+from jax.lax import cummax, sort
 
 from node_replication_tpu.ops.encoding import Dispatch
 
@@ -28,6 +31,13 @@ HM_REMOVE = 2
 HM_GET = 1
 
 ABSENT = -1
+
+
+def hashmap_log_mapper(opcode: int, args: tuple) -> int:
+    """The `cnr` LogMapper of the hashmap (`benches/chashbench.rs:91-100`):
+    the key. Ops on different keys commute, ops on one key share a log
+    (`hash % nlogs`, `core/cnr.py`)."""
+    return args[0]
 
 
 def make_hashmap(n_keys: int, prefill_value: int | None = None) -> Dispatch:
@@ -170,6 +180,59 @@ def make_hashmap(n_keys: int, prefill_value: int | None = None) -> Dispatch:
         # THIS state, so the composition is the full per-replica fold
         return window_merge(state, window_plan(state, opcodes, args))
 
+    def window_apply_stacked(states, opcodes, args, applies):
+        """`window_apply` of every replica of the stacked [R, K] planes,
+        replica r applying the entries `applies[:, r]` marks
+        (`Dispatch.window_apply_stacked`); resps [N, R].
+
+        One sort groups the window's entries by key (window order within
+        a key). Per replica, a cumulative max finds each entry's latest
+        applied predecessor in its group: a REMOVE's presence-before is
+        that entry's PUT-ness, else the replica's own slot; and the
+        group's last applied entry is the replica's final write. Each
+        touched slot's column of replicas is read once and written once
+        (a column keeps the layout of the [R, K] planes: a scatter by
+        (replica, slot) would relay the planes out whole), and a second
+        sort puts the responses back in window order. No op indexes all
+        K slots."""
+        N, R = applies.shape
+        is_put = opcodes == HM_PUT
+        is_rem = opcodes == HM_REMOVE
+        key = jnp.where(is_put | is_rem, args[:, 0] % n_keys, n_keys)
+        pos = jnp.arange(N, dtype=jnp.int32)
+        # (key, window position) is unique: no reliance on sort stability
+        sk, order = sort((key.astype(jnp.int32), pos), num_keys=2)
+        sput, srem, sval = is_put[order], is_rem[order], args[order, 1]
+        live = sk < n_keys
+        sapp = applies[order] & live[:, None]
+        first = jnp.concatenate([jnp.ones((1,), jnp.bool_), sk[1:] != sk[:-1]])
+        last = jnp.concatenate([sk[1:] != sk[:-1], jnp.ones((1,), jnp.bool_)])
+        start = cummax(jnp.where(first, pos, 0))[:, None]
+        # per replica: the latest applied entry at or before each entry
+        upto = cummax(jnp.where(sapp, pos[:, None], -1), axis=0)
+        before = jnp.concatenate([jnp.full((1, R), -1, jnp.int32), upto[:-1]])
+        slot = jnp.minimum(sk, n_keys - 1)
+        old_present = states["present"][:, slot].T
+        old_values = states["values"][:, slot].T
+        pres_before = jnp.where(before >= start, sput[jnp.maximum(before, 0)],
+                                old_present)
+        resp_sorted = jnp.where(sapp & srem[:, None],
+                                pres_before.astype(jnp.int32), 0)
+        wrote = upto >= start
+        fin = jnp.maximum(upto, 0)
+        new_present = jnp.where(wrote, sput[fin], old_present)
+        new_values = jnp.where(wrote, jnp.where(sput[fin], sval[fin], 0),
+                               old_values)
+        dst = jnp.where(last & live, sk, n_keys)
+        states = {
+            "values": states["values"].at[:, dst].set(new_values.T,
+                                                      mode="drop"),
+            "present": states["present"].at[:, dst].set(new_present.T,
+                                                        mode="drop"),
+        }
+        back = sort((order, *resp_sorted.T), num_keys=1)[1:]
+        return states, jnp.stack(back, axis=1)
+
     # fused pallas combiner round (ops/pallas_replay.py): one kernel
     # launch per serve batch — append + replay + response gather on the
     # transposed [K, R] planes. Lazily imported so the model stays
@@ -193,6 +256,7 @@ def make_hashmap(n_keys: int, prefill_value: int | None = None) -> Dispatch:
         # prefix-absorbing plan + canonical responses pinned by
         # tests/test_window.py::test_plan_is_prefix_absorbing
         window_canonical=True,
+        window_apply_stacked=window_apply_stacked,
         fused_factory=fused_factory,
         read_stacked=read_stacked,
     )

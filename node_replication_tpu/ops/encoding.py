@@ -135,6 +135,15 @@ class Dispatch:
     # state: a model whose plan touches a few rows of a large state
     # (`models/records.py`) writes those rows and nothing else.
     window_merge_stacked: Callable | None = None
+    # Optional: `window_apply_stacked(states, opcodes[N], args[N, A],
+    # applies[N, R]) -> (states, resps[N, R])`, `window_apply` of every
+    # replica of the stacked [R, ...] states at once, replica r applying
+    # the window's entries that `applies[:, r]` marks, in window order
+    # (an entry it does not apply answers 0). The multi-log round's
+    # replay (`core/multilog.multilog_exec_window`) runs it in place of
+    # a vmapped `window_apply`: the hashmap's writes each touched slot's
+    # column of replicas and nothing else.
+    window_apply_stacked: Callable | None = None
     # Fused pallas combiner-round engine (optional): a callable
     # `(spec: LogSpec, interpret=None) -> engine` building the model's
     # one-kernel-launch append+replay round (e.g.

@@ -167,11 +167,11 @@ class TestFailedBatchHygiene:
         orig = MultiLogReplicated._exec_round
         state = {"fail": True}
 
-        def flaky(self_ml, log_idx):
+        def flaky(self_ml):
             if state["fail"]:
                 state["fail"] = False
                 raise RuntimeError("injected replay failure")
-            return orig(self_ml, log_idx)
+            return orig(self_ml)
 
         monkeypatch.setattr(MultiLogReplicated, "_exec_round", flaky)
         with pytest.raises(RuntimeError):
@@ -181,13 +181,11 @@ class TestFailedBatchHygiene:
         resps = ml.execute_mut_batch(
             [(SR_SET, 0, 2), (SR_SET, 1, 2)], rid=0
         )
-        # the failure hit during log 0's replay: slot 0's write was
-        # already appended (it replays; only its response was lost),
-        # while log 1's sub-batch was never appended — sub-batches
-        # are per-log combiner passes, not a cross-log transaction.
-        # Either way the sink is clean and the next batch's responses
-        # are exactly its own.
-        assert resps == [1, 0]
+        # the failure hit during the round's replay: one program had
+        # already appended both logs' sub-batches, so both writes
+        # replay (only their responses were lost). The next batch's
+        # responses are exactly its own.
+        assert resps == [1, 1]
 
     def test_worker_guard_rejects_whole_batch(self):
         # an exception OUTSIDE the execute try-block (here: a metrics
